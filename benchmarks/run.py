@@ -115,8 +115,8 @@ def bench_kernels() -> None:
 
     x = jax.random.normal(ks[0], (512, 512), jnp.float32)
     t0 = time.perf_counter()
-    qq, s, meta = quantize_int8(x)
-    dequantize_int8(qq, s, meta).block_until_ready()
+    qq, s, meta = quantize_int8(x, interpret=True)
+    dequantize_int8(qq, s, meta, interpret=True).block_until_ready()
     emit("kernel_quantize_roundtrip_interpret", (time.perf_counter() - t0) * 1e6, "512x512 int8")
 
 

@@ -22,7 +22,7 @@ def main() -> None:
             seq=64,
             ckpt_dir=ckpt_dir,
             ckpt_every=5,
-        )
+        ).losses
     assert losses[-1] < losses[0], "loss should decrease"
     print(f"\nquickstart OK: loss {losses[0]:.3f} → {losses[-1]:.3f}")
 

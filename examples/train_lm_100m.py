@@ -16,7 +16,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from repro.launch.train import train
-from repro.models.model import ArchConfig
 import repro.configs.llama3_2_1b as llama
 
 
@@ -46,26 +45,15 @@ def main() -> None:
     if args.steps:
         run_kw["steps"] = args.steps
 
-    # register the preset so launch.train can resolve it
-    import repro.configs as configs
-
-    module_name = f"repro.configs.{cfg.name.replace('-', '_')}"
-    import types
-
-    mod = types.ModuleType(module_name)
-    mod.config = lambda: cfg
-    mod.reduced = lambda: cfg
-    sys.modules[module_name] = mod
-
     n_params = cfg.total_params()
     print(f"training {cfg.name}: ~{n_params/1e6:.0f}M params, {run_kw['steps']} steps")
     losses = train(
-        cfg.name,
+        cfg,
         ckpt_dir=args.ckpt_dir,
         resume=args.resume,
         log_every=5,
         **run_kw,
-    )
+    ).losses
     print(f"\nloss {losses[0]:.3f} → {losses[-1]:.3f} over {len(losses)} steps")
     assert losses[-1] < losses[0]
 

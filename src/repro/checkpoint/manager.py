@@ -71,6 +71,9 @@ class CheckpointManager:
         self.channel_context = channel_context
         self.transform = transform
         self.keep = keep
+        #: one quantizer per manager: its backend (Pallas on a TPU, else
+        #: numpy) is resolved once, and ``kernel_calls`` shows which ran
+        self.quantizer = QuantizeInt8(block=256)
         os.makedirs(directory, exist_ok=True)
 
     # ------------------------------------------------------------------ #
@@ -79,10 +82,9 @@ class CheckpointManager:
     def _write_array(self, path: str, name: str, arr: np.ndarray, manifest: Dict) -> None:
         entry: Dict[str, Any] = {"shape": list(arr.shape), "dtype": str(arr.dtype), "transform": self.transform}
         if self.transform == "quantize" and arr.dtype in (np.float32, np.float16) and arr.ndim >= 1 and arr.size >= 256:
-            q = QuantizeInt8(block=256)
             from repro.core import Context
 
-            res = q.obj_enf(Context(0, RequestType.write, arr.nbytes), arr)
+            res = self.quantizer.obj_enf(Context(0, RequestType.write, arr.nbytes), arr)
             qarr, scale = res.content
             payload = qarr.tobytes() + scale.tobytes()
             entry.update(res.meta)

@@ -14,6 +14,8 @@ API (Table 2, enforcement-object row):
 """
 from __future__ import annotations
 
+import functools
+import sys
 import threading
 import zlib
 from dataclasses import dataclass
@@ -454,6 +456,24 @@ def _quantize_blocks_numpy(blocks: np.ndarray):
     return q, scale.astype(np.float32)
 
 
+def _process_runs_jax_on_tpu() -> bool:
+    """True when this process has already initialized JAX on a TPU.
+
+    Never initializes a backend itself: a process that has not touched JAX
+    (a forked stage server, say) stays off the chip, which belongs to the
+    job's own process.
+    """
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 class QuantizeInt8(EnforcementObject):
     """Host-side int8 symmetric per-block quantization transformation.
 
@@ -462,46 +482,50 @@ class QuantizeInt8(EnforcementObject):
     path. Block size is per-row groups of ``block`` elements.
 
     ``obj_enf_batch`` packs the whole batch into one ``[M, block]`` matrix and
-    quantizes it with a single fused call — the Pallas rows kernel when a TPU
-    backend is available (``use_pallas=True`` or auto-detected), else one
-    vectorized numpy pass — instead of N Python-level loops.
+    quantizes it with a single fused call — the Pallas rows kernel, else one
+    vectorized numpy pass — instead of N Python-level loops. ``use_pallas``
+    None picks the kernel only where this process already runs JAX on a TPU;
+    True always runs it (``interpret=True`` in the Pallas interpreter). A
+    kernel failure propagates: it never falls back to numpy.
     """
 
     kind = "quantize_int8"
 
-    def __init__(self, block: int = 256, use_pallas: Optional[bool] = None) -> None:
+    def __init__(
+        self, block: int = 256, use_pallas: Optional[bool] = None, interpret: bool = False
+    ) -> None:
         self.block = int(block)
-        #: None = auto (TPU backend only); the numpy path is the CPU fallback
         self.use_pallas = use_pallas
+        self.interpret = bool(interpret)
+        #: batches quantized by the Pallas kernel (the numpy path is not counted)
+        self.kernel_calls = 0
         self._pallas_rows = None  # resolved lazily; jax import stays off core
 
     def _resolve_pallas(self):
-        if self._pallas_rows is not None:
-            return self._pallas_rows if self._pallas_rows is not False else None
-        want = self.use_pallas
-        if want is None or want:
-            try:
-                import jax
-
+        if self._pallas_rows is None:
+            # lane-aligned blocks only; otherwise the tile padding would
+            # change per-block scales vs the numpy semantics
+            aligned = self.block % 128 == 0
+            if self.use_pallas and not aligned:
+                raise ValueError(f"Pallas quantize needs a block multiple of 128, got {self.block}")
+            want = self.use_pallas
+            if want is None:
+                want = aligned and _process_runs_jax_on_tpu()
+            if want:
                 from repro.kernels.quantize.ops import quantize_rows_int8
 
-                on_tpu = jax.default_backend() == "tpu"
-                # lane-aligned blocks only; otherwise the tile padding would
-                # change per-block scales vs the numpy semantics
-                if (want or (want is None and on_tpu)) and self.block % 128 == 0:
-                    self._pallas_rows = quantize_rows_int8
-                    return self._pallas_rows
-            except Exception:
-                pass
-        self._pallas_rows = False
-        return None
+                self._pallas_rows = functools.partial(quantize_rows_int8, interpret=self.interpret)
+            else:
+                self._pallas_rows = False
+        return self._pallas_rows or None
 
     def _quantize_blocks(self, blocks: np.ndarray):
         rows = self._resolve_pallas()
-        if rows is not None:
-            q, s = rows(blocks)
-            return np.asarray(q), np.asarray(s)
-        return _quantize_blocks_numpy(blocks)
+        if rows is None:
+            return _quantize_blocks_numpy(blocks)
+        q, s = rows(blocks)
+        self.kernel_calls += 1
+        return np.asarray(q), np.asarray(s)
 
     def obj_enf(self, ctx: Context, request: Any = None) -> Result:
         if request is None:

@@ -1,21 +1,18 @@
 """Jit-able wrapper around the flash-attention Pallas kernel.
 
-Handles layout ([B,S,H,d] ⇄ [B·H,S,d]), padding to block multiples, GQA head
-grouping, and the interpret-mode switch (CPU validation). The model calls
-this through ``attn_core(backend="pallas")``.
+Handles layout ([B,S,H,d] ⇄ [B·H,S,d]), padding to block multiples and GQA
+head grouping. The kernel compiles for the TPU unless a caller passes
+``interpret=True`` (CPU validation). The model calls this through
+``attn_core(backend="pallas")``.
 """
 from __future__ import annotations
 
-import os
-from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from .kernel import flash_attention_bhsd
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(
@@ -28,7 +25,7 @@ def flash_attention(
     sliding_window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """TPU flash attention; q/k/v may have different head counts (GQA).
 
@@ -44,7 +41,6 @@ def flash_attention(
         causal = mask.causal if mask is not None else True
     if mask is not None and sliding_window == 0:
         sliding_window = mask.sliding_window
-    interpret = _INTERPRET if interpret is None else interpret
 
     bq = min(block_q, max(sq, 8))
     bk = min(block_k, max(sk, 8))

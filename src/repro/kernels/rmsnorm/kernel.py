@@ -7,7 +7,7 @@ pass per (block_rows × d) tile: read x once, write y once.
 
 Grid: one step per row-block; the full feature dim stays resident (d ≤ 16k
 at fp32 = 64 KB/row-block-row — with block_rows=256 and d=12288 the tile is
-12 MB fp32 → block_rows is chosen by ``ops`` to fit ~4 MB in VMEM).
+12 MB fp32 → block_rows is chosen by ``ops`` to fit ~2 MB in VMEM).
 """
 from __future__ import annotations
 

@@ -1,19 +1,18 @@
 """Jit-able wrapper: any [..., d] input, VMEM-aware row blocking."""
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from .kernel import rmsnorm_2d
 
-_INTERPRET = jax.default_backend() != "tpu"
-_VMEM_BUDGET = 4 * 1024 * 1024  # leave room for double buffering
+#: one fp32 row-block tile. The kernel holds about six such tiles in VMEM
+#: (input and output, each double-buffered, plus fp32 temporaries), which
+#: must stay under the 16 MiB scoped-VMEM limit of a v5e core.
+_VMEM_BUDGET = 2 * 1024 * 1024
 
 
-def rms_norm_fused(x: jax.Array, scale: jax.Array, eps: float = 1e-5, interpret: Optional[bool] = None):
-    interpret = _INTERPRET if interpret is None else interpret
+def rms_norm_fused(x: jax.Array, scale: jax.Array, eps: float = 1e-5, interpret: bool = False):
     d = x.shape[-1]
     lead = x.shape[:-1]
     rows = 1

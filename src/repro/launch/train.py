@@ -12,9 +12,8 @@ CPU smoke test and a 512-chip pod (mesh shape from flags).
 from __future__ import annotations
 
 import argparse
-import os
-import time
-from typing import Optional
+import dataclasses
+from typing import Any, Optional, Union
 
 import numpy as np
 
@@ -35,6 +34,7 @@ from repro.core import (
 from repro.data import DataPipeline, SyntheticTokenSource
 from repro.distributed.sharding import sharding_rules
 from repro.ft import HeartbeatMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import (
     TrainConfig,
@@ -43,6 +43,7 @@ from repro.launch.steps import (
     make_state_shardings,
     rules_for,
 )
+from repro.models.model import ArchConfig
 from repro.optim import AdamWConfig, cosine_schedule
 from repro.telemetry import StepTimer
 import repro.configs as configs
@@ -72,8 +73,19 @@ def build_io_stage(total_bandwidth: float = 512e6) -> tuple[Stage, ControlPlane]
     return stage, cp
 
 
+@dataclasses.dataclass
+class TrainRun:
+    """What one ``train`` call leaves behind."""
+
+    losses: list
+    #: the final train state, placed with the mesh's state shardings
+    state: Any
+    #: the I/O stage's statistics (fetch and checkpoint channels) at the end
+    io_stats: Any
+
+
 def train(
-    arch: str,
+    arch: Union[str, ArchConfig],
     steps: int = 20,
     batch: int = 8,
     seq: int = 128,
@@ -86,8 +98,14 @@ def train(
     log_every: int = 1,
     reduced: bool = False,
     host: str = "host0",
-) -> list:
-    cfg = configs.get_reduced(arch) if reduced else configs.get(arch)
+    seed: int = 0,
+) -> TrainRun:
+    """Train ``arch`` (a config name, or an ``ArchConfig`` taken as is) for
+    ``steps`` steps; ``seed`` draws the initial params and the token stream."""
+    if isinstance(arch, ArchConfig):
+        cfg = arch
+    else:
+        cfg = configs.get_reduced(arch) if reduced else configs.get(arch)
     mesh = make_mesh(mesh_shape)
     rules = rules_for(cfg, batch_size=batch, mesh=mesh)
 
@@ -95,7 +113,7 @@ def train(
     cp.start()
     monitor = HeartbeatMonitor(dead_after=600.0)
     pipeline = DataPipeline(
-        SyntheticTokenSource(vocab=cfg.vocab, batch=batch, seq=seq), stage=stage
+        SyntheticTokenSource(vocab=cfg.vocab, batch=batch, seq=seq, seed=seed), stage=stage
     )
     ckpt_mgr = ckpt = None
     if ckpt_dir:
@@ -116,10 +134,14 @@ def train(
             out_shardings=(state_shardings, None),
             donate_argnums=0,
         )
-        state = init_train_state(cfg, jax.random.PRNGKey(0))
+        # built in place, sharded: the whole state never lands on one device
+        init_fn = jax.jit(lambda key: init_train_state(cfg, key), out_shardings=state_shardings)
+        state = init_fn(jax.random.PRNGKey(seed))
         start_step = 0
         if resume and ckpt_mgr is not None and (last := latest_step(ckpt_dir)) is not None:
-            state = ckpt_mgr.restore(last, jax.eval_shape(lambda: state))
+            target = jax.eval_shape(lambda: state)
+            del state  # free the fresh state before the restored one lands
+            state = ckpt_mgr.restore(last, target, shardings=state_shardings)
             start_step = last
             print(f"resumed from checkpoint step {last}")
 
@@ -146,7 +168,7 @@ def train(
         {n: f"{s.cumulative_bytes/2**20:.1f}MiB" for n, s in stats.per_channel.items() if s.cumulative_bytes},
     )
     cp.stop()
-    return losses
+    return TrainRun(losses=losses, state=state, io_stats=stats)
 
 
 def main() -> None:
@@ -164,6 +186,7 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true", help="use the smoke-scale config")
     args = ap.parse_args()
     mesh_shape = tuple(int(x) for x in args.mesh.split("x"))
+    enable_compile_cache()
     train(
         args.arch,
         steps=args.steps,

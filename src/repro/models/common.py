@@ -114,10 +114,12 @@ def apply_ffn(p: dict, x: Array) -> Array:
 
 
 def ffn_logical_axes() -> dict:
+    # d_model is the FSDP axis, as in attention, MoE and SSM weights: the FFN
+    # holds most of a dense layer's parameters
     return {
-        "w_gate": ("layers", "embed", "ff"),
-        "w_up": ("layers", "embed", "ff"),
-        "w_down": ("layers", "ff", "embed"),
+        "w_gate": ("layers", "fsdp", "ff"),
+        "w_up": ("layers", "fsdp", "ff"),
+        "w_down": ("layers", "ff", "fsdp"),
     }
 
 

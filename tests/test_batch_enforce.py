@@ -3,7 +3,10 @@ stats totals), vectorized tokenizer exactness, and the token-bucket
 cumulative-admission invariant under batch consume."""
 from __future__ import annotations
 
+import os
 import random
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -344,13 +347,66 @@ class TestTransformationBatches:
         pytest.importorskip("jax")
         ctx = Context(1, RequestType.write, 0)
         arrs = [np.random.default_rng(i).normal(size=(256,)).astype(np.float32) for i in range(5)]
-        qp = QuantizeInt8(block=128, use_pallas=True)  # interpret-mode Pallas off-TPU
+        qp = QuantizeInt8(block=128, use_pallas=True, interpret=True)
         qn = QuantizeInt8(block=128, use_pallas=False)
         rp = qp.obj_enf_batch([ctx] * 5, arrs)
         rn = qn.obj_enf_batch([ctx] * 5, arrs)
         for a, b in zip(rp, rn):
             assert np.array_equal(np.asarray(a.content[0]), b.content[0])
             np.testing.assert_allclose(np.asarray(a.content[1]), b.content[1], rtol=1e-6)
+
+    def test_quantize_kernel_failure_propagates(self, monkeypatch):
+        pytest.importorskip("jax")
+        import repro.kernels.quantize.ops as qops
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("kernel refused")
+
+        monkeypatch.setattr(qops, "quantize_rows_int8", broken)
+        ctx = Context(1, RequestType.write, 0)
+        qp = QuantizeInt8(block=128, use_pallas=True, interpret=True)
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            qp.obj_enf(ctx, np.ones(256, np.float32))
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            qp.obj_enf_batch([ctx] * 2, [np.ones(256, np.float32)] * 2)
+        assert qp.kernel_calls == 0
+
+    def test_quantize_pallas_needs_lane_aligned_block(self):
+        with pytest.raises(ValueError, match="multiple of 128"):
+            QuantizeInt8(block=96, use_pallas=True).obj_enf(
+                Context(1, RequestType.write, 0), np.ones(96, np.float32)
+            )
+
+    def test_quantize_auto_off_tpu_is_numpy(self):
+        jax = pytest.importorskip("jax")
+        jax.devices()  # this process runs JAX, on the CPU
+        q = QuantizeInt8(block=128)
+        q.obj_enf(Context(1, RequestType.write, 0), np.ones(256, np.float32))
+        assert q.kernel_calls == 0
+
+    def test_quantize_auto_never_initializes_jax(self):
+        """A stage process that has not touched JAX stays off the chip."""
+        pytest.importorskip("jax")
+        code = (
+            "import sys, numpy as np\n"
+            "from repro.core import Context, RequestType\n"
+            "from repro.core.objects import QuantizeInt8\n"
+            "q = QuantizeInt8(block=256)\n"
+            "q.obj_enf(Context(1, RequestType.write, 0), np.ones(512, np.float32))\n"
+            "assert 'jax' not in sys.modules, 'QuantizeInt8 imported jax'\n"
+            "import jax\n"
+            "from jax._src import xla_bridge\n"
+            "q = QuantizeInt8(block=256)\n"
+            "q.obj_enf(Context(1, RequestType.write, 0), np.ones(512, np.float32))\n"
+            "assert not xla_bridge.backends_are_initialized(), 'QuantizeInt8 initialized a backend'\n"
+            "assert q.kernel_calls == 0\n"
+        )
+        src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+        proc = subprocess.run(
+            [sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=120
+        )
+        assert proc.returncode == 0, proc.stderr
 
     def test_checksum_batch_matches_per_item(self):
         ck = Checksum()

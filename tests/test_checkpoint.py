@@ -158,3 +158,22 @@ class TestCheckpointManager:
         burst = rate * 0.1  # initial bucket capacity passes unpaced
         expected = (total - burst) / rate
         assert elapsed == pytest.approx(expected, rel=0.2)
+
+
+class TestTrainResume:
+    def test_resume_restores_onto_the_state_shardings(self, tmp_path):
+        """train() takes an ArchConfig as is, and a resumed run continues from
+        the saved state, placed with the mesh's state shardings."""
+        import repro.configs as configs
+        from repro.launch.train import train
+
+        cfg = configs.get_reduced("llama3_2_1b").replace(name="lm-resume-test", n_layers=1)
+        kw = dict(batch=2, seq=16, ckpt_dir=str(tmp_path), ckpt_every=2, seed=3)
+        first = train(cfg, steps=2, **kw)
+        assert len(first.losses) == 2 and latest_step(str(tmp_path)) == 2
+        resumed = train(cfg, steps=3, resume=True, **kw)
+        assert len(resumed.losses) == 1 and np.isfinite(resumed.losses[0])
+        # the optimizer step counter came back from the checkpoint
+        assert int(resumed.state["opt"]["step"]) == 3
+        embed = resumed.state["params"]["embed"]
+        assert embed.sharding.spec == first.state["params"]["embed"].sharding.spec

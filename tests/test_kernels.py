@@ -96,8 +96,8 @@ class TestQuantize:
     @pytest.mark.parametrize("shape", [(1000,), (33, 77), (5, 17, 23), (256, 128), (1, 1)])
     def test_roundtrip_error_bound(self, shape):
         x = jnp.array(np.random.default_rng(1).normal(size=shape), jnp.float32)
-        q, s, meta = quantize_int8(x)
-        back = dequantize_int8(q, s, meta)
+        q, s, meta = quantize_int8(x, interpret=True)
+        back = dequantize_int8(q, s, meta, interpret=True)
         assert back.shape == x.shape and back.dtype == x.dtype
         # per-block bound: err <= scale/2 + rounding slack; global bound via absmax
         bound = float(np.max(np.abs(np.array(x)))) / 127.0 * 1.01 + 1e-7
@@ -110,15 +110,15 @@ class TestQuantize:
     @settings(max_examples=10, deadline=None)
     def test_roundtrip_property(self, n, scale_mag):
         x = jnp.array(np.random.default_rng(n).normal(size=(n,)) * scale_mag, jnp.float32)
-        q, s, meta = quantize_int8(x)
-        back = dequantize_int8(q, s, meta)
+        q, s, meta = quantize_int8(x, interpret=True)
+        back = dequantize_int8(q, s, meta, interpret=True)
         bound = float(np.max(np.abs(np.array(x)))) / 127.0 * 1.01 + 1e-7
         assert float(np.max(np.abs(np.array(back) - np.array(x)))) <= bound
 
     def test_bf16_input(self):
         x = jnp.array(np.random.default_rng(2).normal(size=(128, 128)), jnp.bfloat16)
-        q, s, meta = quantize_int8(x)
-        back = dequantize_int8(q, s, meta)
+        q, s, meta = quantize_int8(x, interpret=True)
+        back = dequantize_int8(q, s, meta, interpret=True)
         assert back.dtype == jnp.bfloat16
 
 
