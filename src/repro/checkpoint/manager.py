@@ -17,8 +17,15 @@ Design (paper §5 applied to the training stack):
   devices), so a checkpoint taken on one mesh restores onto any other mesh —
   the loader shards according to the target sharding tree.
 * **Async**: ``AsyncCheckpointer`` snapshots device arrays to host on the
-  caller's thread (cheap, consistent) and performs enforcement + file I/O on
-  a worker thread, overlapping checkpoint writes with training compute.
+  caller's thread (consistent: it first waits for the step that produced
+  them) and performs enforcement + file I/O on a worker thread, overlapping
+  checkpoint writes with training compute.
+
+Spans (:mod:`repro.telemetry.spans`): ``paio.ckpt.snapshot`` on the caller's
+thread, with children ``paio.ckpt.ready`` (waiting for the state's producing
+step) and ``paio.ckpt.d2h`` (the copy); on the writing thread
+``paio.ckpt.write`` per leaf (transform, enforcement, write, fsync) and
+``paio.ckpt.commit`` (manifest, rename, old steps removed).
 """
 from __future__ import annotations
 
@@ -35,6 +42,7 @@ import jax
 
 from repro.core import BG_CHECKPOINT, ArrayInstance, RequestType, Stage, propagate_context
 from repro.core.objects import QuantizeInt8
+from repro.telemetry.spans import span
 
 PyTree = Any
 
@@ -74,11 +82,29 @@ class CheckpointManager:
         #: one quantizer per manager: its backend (Pallas on a TPU, else
         #: numpy) is resolved once, and ``kernel_calls`` shows which ran
         self.quantizer = QuantizeInt8(block=256)
+        #: bytes that ``snapshot`` copied from the device to the host
+        self.d2h_bytes = 0
+        self._d2h_lock = threading.Lock()
         os.makedirs(directory, exist_ok=True)
 
     # ------------------------------------------------------------------ #
     # save                                                                #
     # ------------------------------------------------------------------ #
+    def snapshot(self, state: PyTree) -> PyTree:
+        """``state`` on the host, as numpy arrays. Device arrays are first
+        waited for, then copied; their bytes add to ``d2h_bytes``."""
+        on_device = [x for x in jax.tree_util.tree_leaves(state) if isinstance(x, jax.Array)]
+        if not on_device:
+            return jax.tree_util.tree_map(np.asarray, state)
+        with span("paio.ckpt.snapshot"):
+            with span("paio.ckpt.ready"):
+                jax.block_until_ready(on_device)
+            with span("paio.ckpt.d2h"):
+                host_state = jax.tree_util.tree_map(lambda a: np.asarray(jax.device_get(a)), state)
+        with self._d2h_lock:
+            self.d2h_bytes += sum(x.nbytes for x in on_device)
+        return host_state
+
     def _write_array(self, path: str, name: str, arr: np.ndarray, manifest: Dict) -> None:
         entry: Dict[str, Any] = {"shape": list(arr.shape), "dtype": str(arr.dtype), "transform": self.transform}
         if self.transform == "quantize" and arr.dtype in (np.float32, np.float16) and arr.ndim >= 1 and arr.size >= 256:
@@ -117,24 +143,26 @@ class CheckpointManager:
 
     def save(self, step: int, state: PyTree, extra: Optional[Dict[str, Any]] = None) -> str:
         """Blocking save of a (host or device) pytree. Returns final path."""
-        host_state = jax.tree_util.tree_map(lambda a: np.asarray(jax.device_get(a)), state)
+        host_state = self.snapshot(state)
         tmp = os.path.join(self.directory, f"step_{step}.tmp")
         final = os.path.join(self.directory, f"step_{step}")
         os.makedirs(tmp, exist_ok=True)
         manifest: Dict[str, Any] = {"step": step, "tensors": {}, "extra": extra or {}}
         for name, arr in _flatten_with_names(host_state):
-            self._write_array(tmp, name, arr, manifest)
-        mpath = os.path.join(tmp, "manifest.json")
-        with open(mpath, "w") as f:
-            json.dump(manifest, f)
-            f.flush()
-            os.fsync(f.fileno())
-        if os.path.isdir(final):  # overwrite-safe
-            import shutil
+            with span("paio.ckpt.write", leaf=name):
+                self._write_array(tmp, name, arr, manifest)
+        with span("paio.ckpt.commit", step=step):
+            mpath = os.path.join(tmp, "manifest.json")
+            with open(mpath, "w") as f:
+                json.dump(manifest, f)
+                f.flush()
+                os.fsync(f.fileno())
+            if os.path.isdir(final):  # overwrite-safe
+                import shutil
 
-            shutil.rmtree(final)
-        os.rename(tmp, final)
-        self._gc()
+                shutil.rmtree(final)
+            os.rename(tmp, final)
+            self._gc()
         return final
 
     def _gc(self) -> None:
@@ -207,7 +235,7 @@ class AsyncCheckpointer:
         self.errors: List[BaseException] = []
 
     def save(self, step: int, state: PyTree, extra: Optional[Dict[str, Any]] = None) -> None:
-        host_state = jax.tree_util.tree_map(lambda a: np.asarray(jax.device_get(a)), state)
+        host_state = self.manager.snapshot(state)
 
         def work() -> None:
             try:

@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.ft.monitor import HeartbeatMonitor, StragglerReport
+from repro.telemetry.spans import span
 
 # submodule imports (not the repro.transport package) so that importing
 # repro.transport first doesn't hit a partially-initialized package cycle
@@ -1109,8 +1110,13 @@ class ControlPlane:
         return merged_acc
 
     def run_once(self, gated: bool = False) -> Dict[str, List[EnforcementRule]]:
+        with span("paio.control.tick"):
+            return self._tick(gated)
+
+    def _tick(self, gated: bool) -> Dict[str, List[EnforcementRule]]:
         now = self._clock.now()
-        stats = self._collect_all()
+        with span("paio.control.collect"):
+            stats = self._collect_all()
         if self.keep_history:
             self.history.append(stats)
         # objects held by FIRED policy triggers: algorithm tuning is suppressed
@@ -1122,22 +1128,24 @@ class ControlPlane:
         # one fan-out (stages in parallel, per-stage order preserved), so the
         # tick's rule latency is max(stage), not sum over algorithms × stages
         to_ship: Dict[str, List[EnforcementRule]] = {}
-        for algorithm in self._algorithms():
-            step_stats = self._algorithm_stats(algorithm, stats, now, gated)
-            if step_stats is None:
-                continue
-            for stage_name, stage_rules in algorithm.step(step_stats).items():
-                for rule in stage_rules:
-                    if pinned and (stage_name, rule.channel, rule.object_id) in pinned:
-                        continue
-                    to_ship.setdefault(stage_name, []).append(rule)
-        merged = self._ship_fanout(to_ship)
+        with span("paio.control.step"):
+            for algorithm in self._algorithms():
+                step_stats = self._algorithm_stats(algorithm, stats, now, gated)
+                if step_stats is None:
+                    continue
+                for stage_name, stage_rules in algorithm.step(step_stats).items():
+                    for rule in stage_rules:
+                        if pinned and (stage_name, rule.channel, rule.object_id) in pinned:
+                            continue
+                        to_ship.setdefault(stage_name, []).append(rule)
+        with span("paio.control.ship"):
+            merged = self._ship_fanout(to_ship)
         if self._policy_runtime is not None:
             # trigger evaluation + rule application run under the policy
             # lock: a concurrent install_policy(replace=True) must not
             # interleave with an old trigger firing/releasing, or its rules
             # could land AFTER the delta and override the new version
-            with self._policy_lock:
+            with self._policy_lock, span("paio.control.policy"):
                 trigger_rules: Dict[str, List[Any]] = {}
                 for event in self._policy_runtime.on_collect(self._clock.now(), stats):
                     for stage_name, stage_rules in event.rules.items():

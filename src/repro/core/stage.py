@@ -21,6 +21,8 @@ from collections import OrderedDict
 from functools import lru_cache
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from repro.telemetry.spans import span
+
 from .channel import DEFAULT_OBJECT_ID, Channel, group_dispatch, routing_without
 from .clock import Clock, DEFAULT_CLOCK
 from .context import Context
@@ -190,13 +192,14 @@ class Stage:
     # enforcement (Instance API: ``enforce``)                            #
     # ------------------------------------------------------------------ #
     def enforce(self, ctx: Context, request: Any = None) -> Result:
-        name = self.select_channel(ctx)
-        chan = self._channels.get(name)
-        if chan is None:
-            chan = self._channels.get(DEFAULT_CHANNEL)
-            if chan is None:  # stage with no channels: pass through
-                return Result(content=request)
-        return chan.enforce(ctx, request)
+        with span("paio.stage.enforce"):
+            name = self.select_channel(ctx)
+            chan = self._channels.get(name)
+            if chan is None:
+                chan = self._channels.get(DEFAULT_CHANNEL)
+                if chan is None:  # stage with no channels: pass through
+                    return Result(content=request)
+            return chan.enforce(ctx, request)
 
     def enforce_batch(
         self, ctxs: Sequence[Context], requests: Optional[Sequence[Any]] = None
@@ -206,9 +209,15 @@ class Stage:
         Elementwise equivalent to calling ``enforce`` per request, but pays
         routing, lock and dispatch cost per *batch*.
         """
-        n = len(ctxs)
-        if n == 0:
+        if len(ctxs) == 0:
             return []
+        with span("paio.stage.enforce"):
+            return self._enforce_batch(ctxs, requests)
+
+    def _enforce_batch(
+        self, ctxs: Sequence[Context], requests: Optional[Sequence[Any]]
+    ) -> List[Result]:
+        n = len(ctxs)
         c0 = ctxs[0]
         if all(c is c0 for c in ctxs):  # homogeneous submit loop fast path
             chan = self._channels.get(self.select_channel(c0)) or self._channels.get(

@@ -73,6 +73,11 @@ class StatsSnapshot:
     #: rates) are derived control-plane side from the merged raw counts,
     #: never averaged from pre-divided members
     extras: Dict[str, float] = field(default_factory=dict)
+    #: total scheduling delay imposed since the channel was created: unlike
+    #: ``wait_seconds`` no collect resets it, so any reader (not only the
+    #: control plane that owns the collect ticks) can take a run's total as
+    #: the difference of two snapshots. Old-wire peers read 0
+    cumulative_wait_seconds: float = 0.0
 
     def __post_init__(self) -> None:
         # v1 JSON transports round-trip tuples as lists; normalize so wire
@@ -107,8 +112,8 @@ def _hist_percentiles(counts: Sequence[int]) -> Tuple[float, float, float]:
 
 class ChannelStats:
     __slots__ = (
-        "_lock", "_clock", "_ops", "_bytes", "_cum_ops", "_cum_bytes", "_window_start",
-        "_inflight", "_wait", "_hist", "_last_percentiles", "name"
+        "_lock", "_clock", "_ops", "_bytes", "_cum_ops", "_cum_bytes", "_cum_wait",
+        "_window_start", "_inflight", "_wait", "_hist", "_last_percentiles", "name"
     )
 
     def __init__(self, name: str, clock: Clock = DEFAULT_CLOCK) -> None:
@@ -119,6 +124,7 @@ class ChannelStats:
         self._bytes = 0
         self._cum_ops = 0
         self._cum_bytes = 0
+        self._cum_wait = 0.0
         self._inflight = 0
         self._wait = 0.0
         #: windowed wait histogram (bucket counts over WAIT_BOUNDS_MS), reset
@@ -200,9 +206,10 @@ class ChannelStats:
             window = max(now - self._window_start, 1e-9)
             ops, nbytes, wait = self._ops, self._bytes, self._wait
             cum_ops, cum_bytes = self._cum_ops + ops, self._cum_bytes + nbytes
+            cum_wait = self._cum_wait + wait
             inflight = self._inflight
             hist = tuple(self._hist)
-            self._cum_ops, self._cum_bytes = cum_ops, cum_bytes
+            self._cum_ops, self._cum_bytes, self._cum_wait = cum_ops, cum_bytes, cum_wait
             self._ops = 0
             self._bytes = 0
             self._wait = 0.0
@@ -229,6 +236,7 @@ class ChannelStats:
             wait_p95_ms=percentiles[1],
             wait_p99_ms=percentiles[2],
             wait_hist=hist,
+            cumulative_wait_seconds=cum_wait,
         )
 
 
@@ -267,6 +275,7 @@ def merge_snapshots(a: StatsSnapshot, b: StatsSnapshot) -> StatsSnapshot:
         wait_p99_ms=p99,
         wait_hist=hist,
         extras=_sum_extras((a.extras, b.extras)),
+        cumulative_wait_seconds=b.cumulative_wait_seconds,
     )
 
 
@@ -309,6 +318,7 @@ def merge_parallel(snaps: Iterable[StatsSnapshot], channel: str) -> StatsSnapsho
         wait_p99_ms=p99,
         wait_hist=hist,
         extras=_sum_extras(s.extras for s in snaps),
+        cumulative_wait_seconds=sum(s.cumulative_wait_seconds for s in snaps),
     )
 
 

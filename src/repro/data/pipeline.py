@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import FG_FETCH, ArrayInstance, RequestType, Stage, propagate_context
 from repro.models.model import ArchConfig
+from repro.telemetry.spans import span
 
 
 class SyntheticTokenSource:
@@ -133,10 +134,11 @@ class DataPipeline:
 
     # -- synchronous read (used by tests and the quickstart) ---------------
     def read_batch(self, index: int) -> np.ndarray:
-        if self.instance is None:
-            return self.source.read(index)
-        with propagate_context(self.channel_context):
-            return self.instance.on_read(self.source.nbytes_per_batch, lambda: self.source.read(index))
+        with span("paio.data.fetch", batch=index):
+            if self.instance is None:
+                return self.source.read(index)
+            with propagate_context(self.channel_context):
+                return self.instance.on_read(self.source.nbytes_per_batch, lambda: self.source.read(index))
 
     # -- background prefetch ------------------------------------------------
     def start(self) -> "DataPipeline":

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import time
 from typing import Any, Optional, Union
 
 import numpy as np
@@ -45,7 +46,7 @@ from repro.launch.steps import (
 )
 from repro.models.model import ArchConfig
 from repro.optim import AdamWConfig, cosine_schedule
-from repro.telemetry import StepTimer
+from repro.telemetry import span
 import repro.configs as configs
 
 
@@ -145,20 +146,22 @@ def train(
             start_step = last
             print(f"resumed from checkpoint step {last}")
 
-        timer = StepTimer()
         losses = []
         for i in range(start_step, steps):
-            tokens = pipeline.read_batch(i)
-            timer.start()
-            state, metrics = step_fn(state, {"tokens": jnp.asarray(tokens)})
-            loss = float(metrics["loss"])
-            dt = timer.stop()
+            with span("paio.train.fetch", step=i):
+                tokens = pipeline.read_batch(i)
+            t0 = time.perf_counter()
+            with span("paio.train.step", step=i):
+                state, metrics = step_fn(state, {"tokens": jnp.asarray(tokens)})
+                loss = float(metrics["loss"])
+            dt = time.perf_counter() - t0
             monitor.beat(host, dt)
             losses.append(loss)
             if i % log_every == 0:
                 print(f"step {i:>5} loss {loss:.4f} grad_norm {float(metrics['grad_norm']):.3f} {dt*1e3:.0f}ms")
             if ckpt is not None and ckpt_every and (i + 1) % ckpt_every == 0:
-                ckpt.save(i + 1, state)
+                with span("paio.train.ckpt", step=i + 1):
+                    ckpt.save(i + 1, state)
         if ckpt is not None:
             ckpt.wait()
 

@@ -6,12 +6,22 @@ an SDS control plane can enforce per-tenant token-rate policies (the paper's
 §5.2 fair-share scenario applied to serving): each tenant's channel holds a
 DRL object whose rate is the tenant's *token budget per second*; Algorithm 2
 redistributes leftover budget when tenants go idle.
+
+Each request records when it was submitted, began its prefill, had its first
+token on the host and had its last (:class:`RequestTimes`); once done, it
+publishes its own ``serve.generate_ms``, ``serve.ttft_ms`` and
+``serve.queue_wait_ms``. Spans (:mod:`repro.telemetry.spans`): ``paio.serve.admit``
+(a drain's admission through the stage), ``paio.serve.prefill`` (one
+request's cache init, prefill and first token pull), ``paio.serve.decode``
+(one lockstep step: its enforcement, then per live request its decode call,
+``paio.serve.dispatch``, and its token pull, ``paio.serve.sync``).
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import math
 import queue
-import threading
 import time
 from typing import Dict, List, Optional
 
@@ -25,6 +35,32 @@ from repro.launch.steps import build_decode_step, build_prefill_step
 from repro.models import init_caches
 from repro.models.model import ArchConfig
 from repro.telemetry.metrics import MetricRegistry, get_registry
+from repro.telemetry.spans import span
+
+
+@dataclasses.dataclass
+class RequestTimes:
+    """When one request reached each point of the engine (``time.perf_counter``
+    seconds; NaN until reached)."""
+
+    submitted: float
+    prefill_start: float = math.nan
+    #: its first generated token is on the host
+    first_token: float = math.nan
+    #: its last generated token is on the host
+    done: float = math.nan
+
+    @property
+    def queue_wait_s(self) -> float:
+        return self.prefill_start - self.submitted
+
+    @property
+    def ttft_s(self) -> float:
+        return self.first_token - self.submitted
+
+    @property
+    def latency_s(self) -> float:
+        return self.done - self.submitted
 
 
 @dataclasses.dataclass
@@ -32,6 +68,8 @@ class GenerationResult:
     tokens: List[int]
     prompt_len: int
     tenant: Optional[str] = None
+    request_id: Optional[int] = None
+    times: Optional[RequestTimes] = None
 
 
 @dataclasses.dataclass
@@ -41,6 +79,8 @@ class _Pending:
     prompts: np.ndarray
     max_new_tokens: int
     tenant: Optional[str]
+    request_id: int = -1
+    times: RequestTimes = dataclasses.field(default_factory=lambda: RequestTimes(time.perf_counter()))
 
 
 @dataclasses.dataclass
@@ -92,6 +132,7 @@ class ServeEngine:
         self._prefill = jax.jit(build_prefill_step(cfg))
         self._decode = jax.jit(build_decode_step(cfg), donate_argnums=1)
         self._queue: "queue.Queue[_Pending]" = queue.Queue()
+        self._request_ids = itertools.count()
 
     def _describe_once(self, key: str, family: str, labels=None) -> None:
         # descriptors are immutable per key: describe once, not per decode
@@ -100,19 +141,34 @@ class ServeEngine:
             self._registry.describe(key, family, labels)
             self._described.add(key)
 
-    def _publish(self, tenant: Optional[str], n_tokens: int, elapsed_s: float) -> None:
-        """One completed generation's telemetry: per-tenant token counter plus
-        a windowed generation-latency summary (p50/p95/p99 on the exporter).
-        ``elapsed_s`` is the wall time the request experienced end to end —
-        drain() passes its lockstep window's full duration for every request
-        in the window (they all finish when the window does), so the summary
-        means the same thing for queued and direct generations."""
-        tenant = tenant or "default"
+    def _publish(self, p: _Pending, n_tokens: int) -> None:
+        """One completed request's telemetry: the per-tenant token counter,
+        and windowed summaries (p50/p95/p99 on the exporter) of its own times
+        from submission: to its last token on the host (``generate_ms``), to
+        its first (``ttft_ms``) and to the start of its prefill
+        (``queue_wait_ms``)."""
+        tenant = p.tenant or "default"
         key = f"serve.{tenant}.tokens"
         self._registry.inc(key, float(n_tokens))
         self._describe_once(key, "paio_serve_tokens", {"tenant": tenant})
-        self._registry.observe("serve.generate_ms", elapsed_s * 1e3)
+        t = p.times
+        self._registry.observe("serve.generate_ms", t.latency_s * 1e3)
         self._describe_once("serve.generate_ms", "paio_serve_generate_ms")
+        self._registry.observe("serve.ttft_ms", t.ttft_s * 1e3)
+        self._describe_once("serve.ttft_ms", "paio_serve_ttft_ms")
+        self._registry.observe("serve.queue_wait_ms", t.queue_wait_s * 1e3)
+        self._describe_once("serve.queue_wait_ms", "paio_serve_queue_wait_ms")
+
+    def _results(self, lv: "_Live") -> List[GenerationResult]:
+        """A finished request's results, its telemetry published."""
+        p = lv.pending
+        self._publish(p, sum(len(o) for o in lv.outs))
+        return [
+            GenerationResult(
+                tokens=o, prompt_len=lv.prompt_len, tenant=p.tenant, request_id=p.request_id, times=p.times
+            )
+            for o in lv.outs
+        ]
 
     def _publish_step(self, elapsed_s: float) -> None:
         """One decode step's wall time (drain: the lockstep step across all
@@ -135,7 +191,7 @@ class ServeEngine:
         tenant: Optional[str] = None,
     ) -> None:
         """Queue a generation request; ``drain`` admits and runs the queue."""
-        self._queue.put(_Pending(np.asarray(prompts), int(max_new_tokens), tenant))
+        self._queue.put(_Pending(np.asarray(prompts), int(max_new_tokens), tenant, next(self._request_ids)))
 
     def _admit_batch(self, pending: List[_Pending]) -> None:
         """Enforce the queued requests' prefill token cost as ONE batch.
@@ -177,22 +233,35 @@ class ServeEngine:
 
     def _prefill_one(self, p: _Pending) -> _Live:
         b, s0 = p.prompts.shape
-        caches = init_caches(self.cfg, b, self.max_seq, dtype=self.cfg.compute_dtype)
-        batch = {
-            "tokens": jnp.asarray(p.prompts, jnp.int32),
-            "positions": jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32), (b, s0)),
-        }
-        next_tok, caches = self._prefill(self.params, caches, batch)
-        outs = [[int(t)] for t in np.asarray(next_tok)[:, 0]]
+        p.times.prefill_start = time.perf_counter()
+        with span("paio.serve.prefill", request=p.request_id):
+            caches = init_caches(self.cfg, b, self.max_seq, dtype=self.cfg.compute_dtype)
+            batch = {
+                "tokens": jnp.asarray(p.prompts, jnp.int32),
+                "positions": jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32), (b, s0)),
+            }
+            next_tok, caches = self._prefill(self.params, caches, batch)
+            outs = [[int(t)] for t in np.asarray(next_tok)[:, 0]]
+        p.times.first_token = time.perf_counter()
+        if p.max_new_tokens <= 1:
+            p.times.done = p.times.first_token
         return _Live(p, caches, next_tok, outs, b, s0)
 
     def _decode_one_step(self, lv: _Live, step: int) -> None:
+        # one call per live request: the dispatch and sync spans are per call,
+        # at most ``drain_concurrency`` of each in a lockstep step
+        rid = lv.pending.request_id
         pos = jnp.full((lv.batch, 1), lv.prompt_len + step - 1, jnp.int32)
-        lv.next_tok, lv.caches = self._decode(
-            self.params, lv.caches, {"tokens": lv.next_tok, "positions": pos}
-        )
-        for i, t in enumerate(np.asarray(lv.next_tok)[:, 0]):
+        with span("paio.serve.dispatch", request=rid):
+            lv.next_tok, lv.caches = self._decode(
+                self.params, lv.caches, {"tokens": lv.next_tok, "positions": pos}
+            )
+        with span("paio.serve.sync", request=rid):
+            tokens = np.asarray(lv.next_tok)[:, 0]
+        for i, t in enumerate(tokens):
             lv.outs[i].append(int(t))
+        if step + 1 >= lv.pending.max_new_tokens:
+            lv.pending.times.done = time.perf_counter()
 
     def drain(self, max_concurrent: Optional[int] = None) -> List[GenerationResult]:
         """Drain the submit queue with batched enforcement end to end.
@@ -220,9 +289,9 @@ class ServeEngine:
                 break
         if not pending:
             return []
-        self._admit_batch(pending)
+        with span("paio.serve.admit", requests=len(pending)):
+            self._admit_batch(pending)
         results: List[GenerationResult] = []
-        t0 = time.monotonic()  # queue wait across earlier windows counts too
         for at in range(0, len(pending), window_size):
             window = pending[at : at + window_size]
             lives = [self._prefill_one(p) for p in window]
@@ -232,21 +301,14 @@ class ServeEngine:
                 if not active:
                     break
                 ts = time.monotonic()
-                self._enforce_step_batch(active)
-                for lv in active:
-                    self._decode_one_step(lv, step)
+                with span("paio.serve.decode", step=step, live=len(active)):
+                    self._enforce_step_batch(active)
+                    for lv in active:
+                        self._decode_one_step(lv, step)
                 self._publish_step(time.monotonic() - ts)
                 step += 1
-            elapsed = time.monotonic() - t0
             for lv in lives:
-                # each request experiences its window's duration PLUS the
-                # time spent queued behind earlier windows of this drain —
-                # publish that full span, not a per-request split
-                self._publish(lv.pending.tenant, sum(len(o) for o in lv.outs), elapsed)
-                results.extend(
-                    GenerationResult(tokens=o, prompt_len=lv.prompt_len, tenant=lv.pending.tenant)
-                    for o in lv.outs
-                )
+                results.extend(self._results(lv))
         return results
 
     def generate(
@@ -258,14 +320,15 @@ class ServeEngine:
     ) -> List[GenerationResult]:
         prompts = np.asarray(prompts)
         b, s0 = prompts.shape
-        t0 = time.monotonic()
+        p = _Pending(prompts, int(max_new_tokens), tenant, next(self._request_ids))
         if not _prefill_admitted:  # drain() already batch-admitted prefill cost
-            self._enforce(tenant, b * s0)  # prefill cost: prompt tokens
-        lv = self._prefill_one(_Pending(prompts, int(max_new_tokens), tenant))
+            with span("paio.serve.admit", requests=1):
+                self._enforce(tenant, b * s0)  # prefill cost: prompt tokens
+        lv = self._prefill_one(p)
         for step in range(1, max_new_tokens):
             ts = time.monotonic()
-            self._enforce(tenant, b)  # one token per sequence
-            self._decode_one_step(lv, step)
+            with span("paio.serve.decode", step=step, live=1):
+                self._enforce(tenant, b)  # one token per sequence
+                self._decode_one_step(lv, step)
             self._publish_step(time.monotonic() - ts)
-        self._publish(tenant, sum(len(o) for o in lv.outs), time.monotonic() - t0)
-        return [GenerationResult(tokens=o, prompt_len=s0, tenant=tenant) for o in lv.outs]
+        return self._results(lv)

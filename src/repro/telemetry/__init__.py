@@ -9,12 +9,12 @@ from .histogram import (
 from .metrics import (
     MetricRegistry,
     MetricSample,
-    ProcIOReader,
-    StepTimer,
     get_registry,
     quantile,
     set_registry,
 )
+from . import spans
+from .spans import SpanRecord, span
 
 #: exporter names resolve lazily (module __getattr__): the data plane imports
 #: repro.telemetry for the registry; it must not pay for http.server unless
@@ -29,8 +29,7 @@ __all__ = [
     "MetricSample",
     "MetricsExporter",
     "NBUCKETS",
-    "ProcIOReader",
-    "StepTimer",
+    "SpanRecord",
     "WAIT_BOUNDS_MS",
     "bucket_index",
     "get_registry",
@@ -41,6 +40,8 @@ __all__ = [
     "quantile_from_counts",
     "render_prometheus",
     "set_registry",
+    "span",
+    "spans",
     "start_exporter",
 ]
 

@@ -1,5 +1,4 @@
-"""Telemetry: /proc I/O counters (paper §4.3's control-plane side channel),
-step-time tracking for the straggler monitor, and the shared metric registry.
+"""Telemetry: the shared metric registry.
 
 The registry started as a policy-engine internal (the trigger engine samples
 it by dotted name); it is now the process-wide observability surface: stage /
@@ -22,7 +21,6 @@ Two naming layers coexist deliberately:
 from __future__ import annotations
 
 import threading
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Mapping, Optional, Sequence, Tuple
@@ -41,34 +39,6 @@ def quantile(sorted_values: List[float], q: float) -> float:
         return 0.0
     k = min(int(q * len(sorted_values)), len(sorted_values) - 1)
     return sorted_values[k]
-
-
-class ProcIOReader:
-    """Reads read_bytes/write_bytes from /proc/<pid>/io (paper §4.3: the
-    control plane compares block-layer counters with stage statistics)."""
-
-    def __init__(self, pid: Optional[int] = None) -> None:
-        import os
-
-        self.path = f"/proc/{pid or os.getpid()}/io"
-        self._last: Dict[str, int] = {}
-
-    def read(self) -> Dict[str, int]:
-        counters: Dict[str, int] = {}
-        try:
-            with open(self.path) as f:
-                for line in f:
-                    key, _, val = line.partition(":")
-                    counters[key.strip()] = int(val)
-        except OSError:
-            pass
-        return counters
-
-    def delta(self) -> Dict[str, int]:
-        now = self.read()
-        d = {k: now.get(k, 0) - self._last.get(k, 0) for k in now}
-        self._last = now
-        return d
 
 
 @dataclass
@@ -310,25 +280,6 @@ class MetricRegistry:
             out.append(MetricSample(name, "gauge", value, fam, labels))
         return out
 
-    # -- bridges -----------------------------------------------------------
-    def register_step_timer(self, name: str, timer: "StepTimer") -> None:
-        """Bridge a StepTimer: exposes ``<name>.mean_ms`` and ``<name>.p99_ms``."""
-        self.register(f"{name}.mean_ms", lambda: timer.mean() * 1e3)
-        self.register(f"{name}.p99_ms", lambda: timer.percentile(99) * 1e3)
-
-    def register_proc_io(self, name: str = "proc_io", pid: Optional[int] = None) -> None:
-        """Bridge /proc I/O counters: ``<name>.read_bytes`` / ``<name>.write_bytes``
-        report the delta since the previous sample (a per-tick rate source).
-        Each metric gets its own reader so the two delta streams stay
-        independent no matter how often either is sampled."""
-
-        def _mk(key: str) -> Callable[[], float]:
-            reader = ProcIOReader(pid)
-            return lambda: float(reader.delta().get(key, 0))
-
-        self.register(f"{name}.read_bytes", _mk("read_bytes"))
-        self.register(f"{name}.write_bytes", _mk("write_bytes"))
-
 
 # --------------------------------------------------------------------------- #
 # process-wide registry                                                        #
@@ -355,34 +306,3 @@ def set_registry(registry: MetricRegistry) -> MetricRegistry:
     with _global_lock:
         prev, _global_registry = _global_registry, registry
     return prev  # type: ignore[return-value]
-
-
-class StepTimer:
-    """Sliding-window step-duration stats; feeds the straggler monitor."""
-
-    def __init__(self, window: int = 50) -> None:
-        self._durations: Deque[float] = deque(maxlen=window)
-        self._lock = threading.Lock()
-        self._t0: Optional[float] = None
-
-    def start(self) -> None:
-        self._t0 = time.monotonic()
-
-    def stop(self) -> float:
-        dt = time.monotonic() - (self._t0 or time.monotonic())
-        with self._lock:
-            self._durations.append(dt)
-        return dt
-
-    def record(self, seconds: float) -> None:
-        with self._lock:
-            self._durations.append(seconds)
-
-    def mean(self) -> float:
-        with self._lock:
-            return sum(self._durations) / len(self._durations) if self._durations else 0.0
-
-    def percentile(self, q: float) -> float:
-        with self._lock:
-            data = sorted(self._durations)
-        return quantile(data, q / 100.0)

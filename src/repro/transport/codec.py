@@ -11,7 +11,7 @@ self-describing binary encoding. Three layers, all little-endian:
   tag per rule dataclass (housekeeping / differentiation / enforcement) and
   ``struct``-packed fields;
 * a **stats codec** (:func:`encode_stats` / :func:`decode_stats`): each
-  :class:`~repro.core.stats.StatsSnapshot` is one fixed 96-byte ``struct``
+  :class:`~repro.core.stats.StatsSnapshot` is one fixed 104-byte ``struct``
   pack plus its channel name plus a sparse run of nonzero wait-histogram
   buckets — the collect hot path never touches a dict.
 
@@ -312,8 +312,8 @@ def decode_rule(payload: bytes):
 #: fixed numeric fields of one StatsSnapshot, in dataclass order after
 #: ``channel``: ops, bytes, window_seconds, throughput, iops, cumulative_ops,
 #: cumulative_bytes, inflight, wait_seconds, wait_p50_ms, wait_p95_ms,
-#: wait_p99_ms
-_SNAP = struct.Struct("<qqdddqqqdddd")
+#: wait_p99_ms; then cumulative_wait_seconds
+_SNAP = struct.Struct("<qqdddqqqddddd")
 #: one sparse wait-histogram entry: bucket index (u8), op count (i64). The
 #: fixed struct is followed by a u8 count of these pairs — a typical window
 #: touches a handful of buckets, so sparse beats shipping all 26 counts
@@ -343,6 +343,7 @@ def encode_stats(stats: StageStats) -> bytes:
             s.wait_p50_ms,
             s.wait_p95_ms,
             s.wait_p99_ms,
+            s.cumulative_wait_seconds,
         )
         if s.wait_hist:
             nonzero = [(i, c) for i, c in enumerate(s.wait_hist) if c]
@@ -380,6 +381,7 @@ def decode_stats(payload: bytes) -> StageStats:
             wait_p50_ms,
             wait_p95_ms,
             wait_p99_ms,
+            cumulative_wait_seconds,
         ) = _SNAP.unpack(r.take(_SNAP.size))
         npairs = r.u8()
         wait_hist: Tuple[int, ...] = ()
@@ -414,6 +416,7 @@ def decode_stats(payload: bytes) -> StageStats:
             wait_p99_ms=wait_p99_ms,
             wait_hist=wait_hist,
             extras=extras,
+            cumulative_wait_seconds=cumulative_wait_seconds,
         )
     if r.off != len(payload):
         raise TransportError(f"{len(payload) - r.off} trailing bytes after stats")

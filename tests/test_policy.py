@@ -511,7 +511,7 @@ class TestSlidingWindow:
         assert w.aggregate("last") == 3.0
         assert w.aggregate("mean") == pytest.approx(4.5)
         assert w.aggregate("min") == 1.0 and w.aggregate("max") == 9.0
-        # nearest-rank percentiles (same scheme as telemetry.StepTimer)
+        # nearest-rank percentiles (same scheme as telemetry.quantile)
         assert w.aggregate("p50") == 5.0 and w.aggregate("p99") == 9.0
 
     def test_pruning(self):

@@ -32,7 +32,7 @@ class TestRegistry:
         sample = r.sample()
         assert sample["g"] == 1.5
         assert sample["c"] == 3.0
-        # nearest-rank (same convention as SlidingWindow/StepTimer)
+        # nearest-rank (same convention as SlidingWindow)
         assert sample["s.p50"] == 51.0
         assert sample["s.p95"] == 96.0
         assert sample["s.p99"] == 100.0

@@ -205,6 +205,7 @@ _snap = st.builds(
     wait_p50_ms=st.floats(min_value=0, allow_nan=False, allow_infinity=False),
     wait_p95_ms=st.floats(min_value=0, allow_nan=False, allow_infinity=False),
     wait_p99_ms=st.floats(min_value=0, allow_nan=False, allow_infinity=False),
+    cumulative_wait_seconds=st.floats(min_value=0, allow_nan=False, allow_infinity=False),
     wait_hist=st.one_of(
         st.just(()),  # old-wire: no histogram
         st.lists(
@@ -309,17 +310,17 @@ def _stage(name: str) -> Stage:
     return stage
 
 
+INTEROP_MATRIX = [
+    ("auto", 2, 2),    # v2 × v2 → binary
+    ("auto", 1, 1),    # v2 client, v1 server → JSON fallback
+    ("json", 2, 1),    # v1 client, v2 server → JSON served
+    ("json", 1, 1),    # v1 × v1 → JSON (the seed protocol)
+    ("binary", 2, 2),  # forced binary against a v2 server
+]
+
+
 class TestInterop:
-    @pytest.mark.parametrize(
-        "client_protocol,server_max,expect_proto",
-        [
-            ("auto", 2, 2),    # v2 × v2 → binary
-            ("auto", 1, 1),    # v2 client, v1 server → JSON fallback
-            ("json", 2, 1),    # v1 client, v2 server → JSON served
-            ("json", 1, 1),    # v1 × v1 → JSON (the seed protocol)
-            ("binary", 2, 2),  # forced binary against a v2 server
-        ],
-    )
+    @pytest.mark.parametrize("client_protocol,server_max,expect_proto", INTEROP_MATRIX)
     def test_matrix_same_semantics(self, stage_dir, client_protocol, server_max, expect_proto):
         stage = _stage("s")
         path = os.path.join(stage_dir, "s.sock")
@@ -345,6 +346,29 @@ class TestInterop:
                 assert handle.enf_rule(
                     EnforcementRule(channel="nope", object_id="0", state={})
                 ) is False
+            finally:
+                handle.close()
+        finally:
+            server.stop()
+
+    @pytest.mark.parametrize("client_protocol,server_max,expect_proto", INTEROP_MATRIX)
+    def test_matrix_carries_the_cumulative_wait(self, stage_dir, client_protocol, server_max, expect_proto):
+        # the run's total wait survives the remote collects that reset the
+        # window, over every protocol pairing
+        stage = _stage("s")
+        path = os.path.join(stage_dir, "s.sock")
+        server = StageServer(stage, path, max_protocol=server_max).start()
+        try:
+            handle = RemoteStageHandle(path, protocol=client_protocol)
+            try:
+                assert handle.proto == expect_proto
+                stats = stage.channel("io").stats
+                stats.record(1, wait=0.25)
+                first = handle.collect().per_channel["io"]
+                stats.record(1, wait=0.5)
+                second = handle.collect().per_channel["io"]
+                assert (first.wait_seconds, first.cumulative_wait_seconds) == (0.25, 0.25)
+                assert (second.wait_seconds, second.cumulative_wait_seconds) == (0.5, 0.75)
             finally:
                 handle.close()
         finally:
