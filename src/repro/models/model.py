@@ -437,13 +437,17 @@ def _apply_slstm_maybe_sharded(cfg: ArchConfig, p_slstm: dict, h, cache, update_
     return sharded(p_slstm, h, cache)
 
 
-def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_seg, update_cache):
-    """Scan a homogeneous segment of layers; caches are stacked on axis 0."""
+#: segment leaves that stay float32 when the rest go to the compute dtype
+#: (their modules upcast internally)
+_KEEP_F32 = frozenset({"A_log", "w_if", "b_if", "router"})
+#: top-level leaves that ``forward`` reads only through a cast to the compute
+#: dtype (``final_norm`` is not one: ``rms_norm`` scales in float32)
+_CAST_TOP = ("embed", "lm_head", "frontend_proj")
 
-    # Cast params to compute dtype BEFORE the layer scan (§Perf: the per-layer
-    # FSDP all-gather then moves bf16, not fp32 — halves weight-gather traffic).
-    # Precision-sensitive leaves stay fp32 (their modules upcast internally).
-    _KEEP_F32 = {"A_log", "w_if", "b_if", "router"}
+
+def _cast_segment(cfg: ArchConfig, p_seg: dict) -> dict:
+    """Every float32 leaf of a segment not in ``_KEEP_F32`` in the compute
+    dtype; a leaf that arrives in another dtype is left as it is."""
 
     def cast_leaf(path, a):
         name = str(path[-1].key) if hasattr(path[-1], "key") else ""
@@ -451,7 +455,32 @@ def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_s
             return a.astype(cfg.compute_dtype)
         return a
 
-    p_seg = jax.tree_util.tree_map_with_path(cast_leaf, p_seg)
+    return jax.tree_util.tree_map_with_path(cast_leaf, p_seg)
+
+
+def cast_for_compute(cfg: ArchConfig, params: dict) -> dict:
+    """``params`` with exactly the casts that ``forward`` applies made ahead of
+    it: ``forward`` on the result computes the same bits and casts nothing
+    more. A leaf that needs no cast is the caller's own array, uncopied.
+
+    For weights that stay fixed across many calls (serving), so that each call
+    reads the compute-dtype copy instead of converting the float32 tree again.
+    """
+    out = dict(params)
+    out["segments"] = [_cast_segment(cfg, p_seg) for p_seg in params["segments"]]
+    for name in _CAST_TOP:
+        if name in out and out[name].dtype == jnp.float32:
+            out[name] = out[name].astype(cfg.compute_dtype)
+    return out
+
+
+def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_seg, update_cache):
+    """Scan a homogeneous segment of layers; caches are stacked on axis 0."""
+
+    # Cast params to compute dtype BEFORE the layer scan (§Perf: the per-layer
+    # FSDP all-gather then moves bf16, not fp32 — halves weight-gather traffic).
+    # A tree from ``cast_for_compute`` arrives cast, and this casts nothing.
+    p_seg = _cast_segment(cfg, p_seg)
 
     def body(carry, xs):
         x_in, aux_in = carry

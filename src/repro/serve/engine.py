@@ -14,7 +14,8 @@ publishes its own ``serve.generate_ms``, ``serve.ttft_ms`` and
 (a drain's admission through the stage), ``paio.serve.prefill`` (one
 request's cache init, prefill and first token pull), ``paio.serve.decode``
 (one lockstep step: its enforcement, then per live request its decode call,
-``paio.serve.dispatch``, and its token pull, ``paio.serve.sync``).
+``paio.serve.dispatch``, and its token pull, ``paio.serve.sync``) and
+``paio.serve.cast_params`` (the weights' one cast to the compute dtype).
 """
 from __future__ import annotations
 
@@ -33,7 +34,7 @@ import jax.numpy as jnp
 from repro.core import RequestType, Stage, build_context, propagate_tenant
 from repro.launch.steps import build_decode_step, build_prefill_step
 from repro.models import init_caches
-from repro.models.model import ArchConfig
+from repro.models.model import ArchConfig, cast_for_compute
 from repro.telemetry.metrics import MetricRegistry, get_registry
 from repro.telemetry.spans import span
 
@@ -103,6 +104,10 @@ class ServeEngine:
     tenant's channel (context-only enforcement — the zero-copy fast path of
     paper §3.4), so token throughput per tenant is shaped by the control
     plane.
+
+    ``params`` is the caller's tree; prefill and decode read a copy cast to
+    the compute dtype (``cast_for_compute``), made once when ``params`` is
+    set, instead of converting the float32 weights again in every call.
     """
 
     def __init__(
@@ -115,7 +120,6 @@ class ServeEngine:
         registry: Optional[MetricRegistry] = None,
     ) -> None:
         self.cfg = cfg
-        self.params = params
         self.max_seq = max_seq
         self.stage = stage
         #: serve statistics publish into the shared process-wide registry by
@@ -123,6 +127,7 @@ class ServeEngine:
         #: pass an explicit registry for isolation
         self._registry = registry if registry is not None else get_registry()
         self._described: set = set()
+        self.params = params
         #: lockstep window of ``drain``: how many queued requests decode (and
         #: hold KV caches) simultaneously. Peak drain memory is roughly
         #: ``drain_concurrency × init_caches(cfg, b, max_seq)`` — size it to
@@ -133,6 +138,26 @@ class ServeEngine:
         self._decode = jax.jit(build_decode_step(cfg), donate_argnums=1)
         self._queue: "queue.Queue[_Pending]" = queue.Queue()
         self._request_ids = itertools.count()
+
+    @property
+    def params(self):
+        """The caller's weights, as given."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        # the copy prefill and decode read; recomputed here so that swapped
+        # weights are never served stale
+        with span("paio.serve.cast_params"):
+            cast = jax.block_until_ready(cast_for_compute(self.cfg, params))
+        self._params, self._compute_params = params, cast
+        precast = sum(
+            c.nbytes
+            for c, p in zip(jax.tree_util.tree_leaves(cast), jax.tree_util.tree_leaves(params))
+            if c is not p
+        )
+        self._registry.set_gauge("serve.params_precast_bytes", float(precast))
+        self._describe_once("serve.params_precast_bytes", "paio_serve_params_precast_bytes")
 
     def _describe_once(self, key: str, family: str, labels=None) -> None:
         # descriptors are immutable per key: describe once, not per decode
@@ -240,7 +265,7 @@ class ServeEngine:
                 "tokens": jnp.asarray(p.prompts, jnp.int32),
                 "positions": jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32), (b, s0)),
             }
-            next_tok, caches = self._prefill(self.params, caches, batch)
+            next_tok, caches = self._prefill(self._compute_params, caches, batch)
             outs = [[int(t)] for t in np.asarray(next_tok)[:, 0]]
         p.times.first_token = time.perf_counter()
         if p.max_new_tokens <= 1:
@@ -254,7 +279,7 @@ class ServeEngine:
         pos = jnp.full((lv.batch, 1), lv.prompt_len + step - 1, jnp.int32)
         with span("paio.serve.dispatch", request=rid):
             lv.next_tok, lv.caches = self._decode(
-                self.params, lv.caches, {"tokens": lv.next_tok, "positions": pos}
+                self._compute_params, lv.caches, {"tokens": lv.next_tok, "positions": pos}
             )
         with span("paio.serve.sync", request=rid):
             tokens = np.asarray(lv.next_tok)[:, 0]
