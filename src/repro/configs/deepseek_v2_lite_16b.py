@@ -1,7 +1,9 @@
 """deepseek-v2-lite-16b [arXiv:2405.04434].
 
 27L d_model=2048 16H MLA (kv_lora=512) d_ff_expert=1408 vocab=102400,
-MoE 64 routed experts top-6 + 2 shared, first layer dense.
+MoE 64 routed experts top-6 (softmax weights, not renormalised) + 2 shared,
+first layer dense; YaRN rotary scaling (factor 40, mscale 0.707); random
+weights at std 0.02 (initializer_range).
 """
 from repro.models.model import ArchConfig
 
@@ -27,6 +29,18 @@ def config() -> ArchConfig:
         qk_nope_dim=128,
         v_head_dim=128,
         tie_embeddings=False,
+        norm_eps=1e-6,
+        norm_topk_prob=False,
+        # YaRN rope_scaling: factor 40 over 4096 original positions
+        rope_scaling_factor=40.0,
+        rope_original_max_pos=4096,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_mscale=0.707,
+        rope_mscale_all_dim=0.707,
+        # random weights as the published modelling code draws them
+        # (initializer_range), not at 1/sqrt(fan-in)
+        init_std=0.02,
     )
 
 
@@ -39,9 +53,9 @@ def reduced() -> ArchConfig:
         n_kv_heads=4,
         d_ff=128,
         d_ff_expert=32,
-        n_experts=4,
-        top_k=2,
-        n_shared_experts=1,
+        n_experts=16,
+        top_k=3,
+        n_shared_experts=2,
         first_k_dense=1,
         vocab=257,
         kv_lora_rank=32,
@@ -49,4 +63,5 @@ def reduced() -> ArchConfig:
         qk_nope_dim=16,
         v_head_dim=16,
         moe_group_size=32,
+        init_std=0.125,  # 1/sqrt(64), as 0.02 is about 1/sqrt(2048)
     )

@@ -180,11 +180,15 @@ def build_train_step(cfg: ArchConfig, tcfg: TrainConfig = TrainConfig()) -> Call
 # serve steps                                                                  #
 # --------------------------------------------------------------------------- #
 def build_prefill_step(cfg: ArchConfig) -> Callable:
+    """(params, caches, batch) → (next_token [B,1], dropped, caches): the head
+    runs on the last position only; ``dropped`` is the expert choices that the
+    capacity bound dropped, summed over layers (0 without MoE)."""
+
     def prefill(params, caches, batch):
-        logits, _, new_caches = model_lib.forward(cfg, params, batch, caches=caches, update_cache=True)
-        logits = model_lib.mask_padded_vocab(cfg, logits)
-        next_token = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_token, new_caches
+        x, _, dropped, new_caches = model_lib.forward_layers(cfg, params, batch, caches=caches, update_cache=True)
+        logits = model_lib.mask_padded_vocab(cfg, model_lib.apply_head(cfg, params, x[:, -1:]))
+        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return next_token, dropped, new_caches
 
     return prefill
 

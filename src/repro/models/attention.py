@@ -27,7 +27,7 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import lsc
 
-from .common import apply_rope, dense_init, rms_norm
+from .common import Yarn, apply_rope, dense_init, rms_norm, yarn_mscale
 
 Array = jax.Array
 
@@ -179,13 +179,15 @@ class KVCache(NamedTuple):
     idx: Array  # [] int32, number of tokens written (ring pointer for SWA)
 
 
-def init_attention(key, n_layers, d_model, n_heads, n_kv_heads, d_head, qk_norm=False, dtype=jnp.float32):
+def init_attention(
+    key, n_layers, d_model, n_heads, n_kv_heads, d_head, qk_norm=False, dtype=jnp.float32, std=None
+):
     ks = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(ks[0], (n_layers, d_model, n_heads, d_head), in_axis=1, dtype=dtype),
-        "wk": dense_init(ks[1], (n_layers, d_model, n_kv_heads, d_head), in_axis=1, dtype=dtype),
-        "wv": dense_init(ks[2], (n_layers, d_model, n_kv_heads, d_head), in_axis=1, dtype=dtype),
-        "wo": dense_init(ks[3], (n_layers, n_heads, d_head, d_model), in_axis=1, dtype=dtype),
+        "wq": dense_init(ks[0], (n_layers, d_model, n_heads, d_head), in_axis=1, dtype=dtype, std=std),
+        "wk": dense_init(ks[1], (n_layers, d_model, n_kv_heads, d_head), in_axis=1, dtype=dtype, std=std),
+        "wv": dense_init(ks[2], (n_layers, d_model, n_kv_heads, d_head), in_axis=1, dtype=dtype, std=std),
+        "wo": dense_init(ks[3], (n_layers, n_heads, d_head, d_model), in_axis=1, dtype=dtype, std=std),
     }
     if qk_norm:
         p["q_norm"] = jnp.ones((n_layers, d_head), dtype)
@@ -327,16 +329,19 @@ def init_mla(
     qk_rope_dim,
     v_head_dim,
     dtype=jnp.float32,
+    std=None,
 ):
     ks = jax.random.split(key, 4)
     return {
-        "wq": dense_init(ks[0], (n_layers, d_model, n_heads, qk_nope_dim + qk_rope_dim), in_axis=1, dtype=dtype),
-        "w_kv_a": dense_init(ks[1], (n_layers, d_model, kv_lora_rank + qk_rope_dim), in_axis=1, dtype=dtype),
+        "wq": dense_init(
+            ks[0], (n_layers, d_model, n_heads, qk_nope_dim + qk_rope_dim), in_axis=1, dtype=dtype, std=std
+        ),
+        "w_kv_a": dense_init(ks[1], (n_layers, d_model, kv_lora_rank + qk_rope_dim), in_axis=1, dtype=dtype, std=std),
         "kv_norm": jnp.ones((n_layers, kv_lora_rank), dtype),
         "w_kv_b": dense_init(
-            ks[2], (n_layers, kv_lora_rank, n_heads, qk_nope_dim + v_head_dim), in_axis=1, dtype=dtype
+            ks[2], (n_layers, kv_lora_rank, n_heads, qk_nope_dim + v_head_dim), in_axis=1, dtype=dtype, std=std
         ),
-        "wo": dense_init(ks[3], (n_layers, n_heads, v_head_dim, d_model), in_axis=1, dtype=dtype),
+        "wo": dense_init(ks[3], (n_layers, n_heads, v_head_dim, d_model), in_axis=1, dtype=dtype, std=std),
     }
 
 
@@ -348,6 +353,14 @@ def mla_logical_axes():
         "w_kv_b": ("layers", None, "heads", None),
         "wo": ("layers", "heads", None, "fsdp"),
     }
+
+
+def mla_softmax_scale(d_qk: int, yarn: Optional[Yarn] = None) -> float:
+    """``d_qk**-0.5``, times mscale(factor, mscale_all_dim)² under YaRN."""
+    scale = d_qk**-0.5
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return scale
 
 
 def apply_mla(
@@ -365,19 +378,19 @@ def apply_mla(
     unroll: bool = False,
     cache: Optional[MLACache] = None,
     update_cache: bool = False,
+    yarn: Optional[Yarn] = None,
 ) -> Tuple[Array, Optional[MLACache]]:
     n_heads = p["wq"].shape[-2]
     kv_lora = p["w_kv_b"].shape[0]  # per-layer slice: [kv_lora, H, nope+v]
-    d_qk = qk_nope_dim + qk_rope_dim
-    scale = d_qk**-0.5
+    scale = mla_softmax_scale(qk_nope_dim + qk_rope_dim, yarn)
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])  # [B,S,H,nope+rope]
     q_nope, q_rope = q[..., :qk_nope_dim], q[..., qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, rope_theta)
+    q_rope = apply_rope(q_rope, positions, rope_theta, yarn=yarn)
 
     kv_a = jnp.einsum("bsd,dk->bsk", x, p["w_kv_a"])  # [B,S,lora+rope]
     c_kv = rms_norm(kv_a[..., :kv_lora], p["kv_norm"], norm_eps)  # [B,S,lora]
-    k_rope = apply_rope(kv_a[..., kv_lora:][:, :, None, :], positions, rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(kv_a[..., kv_lora:][:, :, None, :], positions, rope_theta, yarn=yarn)[:, :, 0, :]
 
     is_decode = cache is not None and x.shape[1] == 1
 

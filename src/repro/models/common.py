@@ -8,7 +8,7 @@ multi-pod compiles, natural remat boundary).
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,15 +21,18 @@ Array = jax.Array
 # --------------------------------------------------------------------------- #
 # init                                                                         #
 # --------------------------------------------------------------------------- #
-def dense_init(key: Array, shape: Sequence[int], in_axis: int = -2, dtype=jnp.float32) -> Array:
-    """Truncated-normal fan-in init (LeCun-style, the MaxText default)."""
-    fan_in = shape[in_axis]
-    std = 1.0 / math.sqrt(fan_in)
+def dense_init(
+    key: Array, shape: Sequence[int], in_axis: int = -2, dtype=jnp.float32, std: Optional[float] = None
+) -> Array:
+    """Truncated-normal fan-in init (LeCun-style, the MaxText default); a
+    given ``std`` replaces 1/sqrt(fan-in)."""
+    if std is None:
+        std = 1.0 / math.sqrt(shape[in_axis])
     return (jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32) * std).astype(dtype)
 
 
-def embed_init(key: Array, shape: Sequence[int], dtype=jnp.float32) -> Array:
-    return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+def embed_init(key: Array, shape: Sequence[int], dtype=jnp.float32, std: float = 0.02) -> Array:
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
 # --------------------------------------------------------------------------- #
@@ -57,8 +60,40 @@ def layer_norm(x: Array, scale: Array, bias: Optional[Array] = None, eps: float 
 # --------------------------------------------------------------------------- #
 # rotary position embeddings                                                   #
 # --------------------------------------------------------------------------- #
-def rope_frequencies(d_rot: int, theta: float = 10000.0) -> Array:
-    return 1.0 / (theta ** (jnp.arange(0, d_rot, 2, dtype=jnp.float32) / d_rot))
+class Yarn(NamedTuple):
+    """YaRN rotary scaling as DeepSeek-V2 defines it (its ``rope_scaling``)."""
+
+    factor: float
+    original_max_pos: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(yarn: Yarn, d_rot: int, theta: float) -> Tuple[int, int]:
+    """The rotary dims between which YaRN's ramp runs: those that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_max_pos`` positions."""
+
+    def dim(rotations: float) -> float:
+        return d_rot * math.log(yarn.original_max_pos / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    return max(math.floor(dim(yarn.beta_fast)), 0), min(math.ceil(dim(yarn.beta_slow)), d_rot - 1)
+
+
+def rope_frequencies(d_rot: int, theta: float = 10000.0, yarn: Optional[Yarn] = None) -> Array:
+    freqs = 1.0 / (theta ** (jnp.arange(0, d_rot, 2, dtype=jnp.float32) / d_rot))
+    if yarn is None:
+        return freqs
+    # below the ramp the frequencies stay, above it they are divided by the
+    # factor, and in between they blend linearly
+    low, high = yarn_correction_range(yarn, d_rot, theta)
+    ramp = jnp.clip((jnp.arange(d_rot // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
 
 
 def apply_rope(
@@ -66,11 +101,13 @@ def apply_rope(
     positions: Array,
     theta: float = 10000.0,
     fraction: float = 1.0,
+    yarn: Optional[Yarn] = None,
 ) -> Array:
     """Apply RoPE to the last dim of ``x`` [..., seq, heads, d_head].
 
     ``fraction`` < 1 rotates only the first ``fraction·d_head`` dims (ChatGLM's
-    2D/partial RoPE); the remainder passes through unrotated.
+    2D/partial RoPE); the remainder passes through unrotated. ``yarn`` blends
+    the frequencies and scales cos and sin by YaRN's mscale ratio.
     """
     d_head = x.shape[-1]
     d_rot = int(d_head * fraction)
@@ -78,10 +115,14 @@ def apply_rope(
     if d_rot == 0:
         return x
     x_rot, x_pass = x[..., :d_rot], x[..., d_rot:]
-    freqs = rope_frequencies(d_rot, theta)  # [d_rot/2]
+    freqs = rope_frequencies(d_rot, theta, yarn)  # [d_rot/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., seq, d_rot/2]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        ratio = yarn_mscale(yarn.factor, yarn.mscale) / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if ratio != 1.0:
+            cos, sin = cos * ratio, sin * ratio
     x1, x2 = jnp.split(x_rot.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     out = jnp.concatenate([rotated.astype(x.dtype), x_pass], axis=-1) if d_rot < d_head else rotated.astype(x.dtype)
@@ -95,12 +136,14 @@ def swiglu(gate: Array, up: Array) -> Array:
     return jax.nn.silu(gate) * up
 
 
-def init_ffn(key: Array, n_layers: int, d_model: int, d_ff: int, dtype=jnp.float32) -> dict:
+def init_ffn(
+    key: Array, n_layers: int, d_model: int, d_ff: int, dtype=jnp.float32, std: Optional[float] = None
+) -> dict:
     k1, k2, k3 = jax.random.split(key, 3)
     return {
-        "w_gate": dense_init(k1, (n_layers, d_model, d_ff), in_axis=-2, dtype=dtype),
-        "w_up": dense_init(k2, (n_layers, d_model, d_ff), in_axis=-2, dtype=dtype),
-        "w_down": dense_init(k3, (n_layers, d_ff, d_model), in_axis=-2, dtype=dtype),
+        "w_gate": dense_init(k1, (n_layers, d_model, d_ff), in_axis=-2, dtype=dtype, std=std),
+        "w_up": dense_init(k2, (n_layers, d_model, d_ff), in_axis=-2, dtype=dtype, std=std),
+        "w_down": dense_init(k3, (n_layers, d_ff, d_model), in_axis=-2, dtype=dtype, std=std),
     }
 
 

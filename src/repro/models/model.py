@@ -32,7 +32,7 @@ from . import attention as attn_mod
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from . import xlstm as xlstm_mod
-from .common import cross_entropy_loss, dense_init, embed_init, init_ffn, apply_ffn, ffn_logical_axes, rms_norm
+from .common import Yarn, cross_entropy_loss, dense_init, embed_init, init_ffn, apply_ffn, ffn_logical_axes, rms_norm
 
 Array = jax.Array
 
@@ -56,6 +56,13 @@ class ArchConfig:
     qk_norm: bool = False
     rope_fraction: float = 1.0
     rope_theta: float = 10000.0
+    # YaRN rotary scaling of MLA (DeepSeek-V2's ``rope_scaling``); factor 1: none
+    rope_scaling_factor: float = 1.0
+    rope_original_max_pos: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     causal: bool = True
     sliding_window: int = 0  # 0 = full attention
     # MLA (DeepSeek-V2)
@@ -64,7 +71,9 @@ class ArchConfig:
     qk_nope_dim: int = 128
     v_head_dim: int = 128
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0  # the router's width: experts routed over
+    n_experts_held: int = 0  # experts whose weights are here (the first ones); 0 = all
+    norm_topk_prob: bool = True  # renormalise the top-k weights to sum to one
     top_k: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
@@ -97,11 +106,30 @@ class ArchConfig:
     remat: bool = False
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.bfloat16
+    # random init: 0 draws each matrix at 1/sqrt(fan-in); > 0 draws every
+    # matrix but the router, and the embedding, at this std (a checkpoint's
+    # ``initializer_range``)
+    init_std: float = 0.0
+
+    def __post_init__(self):
+        if self.rope_scaling_factor != 1.0 and self.attn_type != "mla":
+            raise ValueError("rope_scaling_factor (YaRN) is implemented for MLA only")
+        if self.init_std and any(kind not in ("dense", "moe") for kind, _ in self.segments()):
+            raise ValueError("init_std covers dense and MoE layers only")
 
     # -- derived ----------------------------------------------------------
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def yarn(self) -> Optional[Yarn]:
+        if self.rope_scaling_factor == 1.0:
+            return None
+        return Yarn(
+            self.rope_scaling_factor, self.rope_original_max_pos, self.rope_beta_fast,
+            self.rope_beta_slow, self.rope_mscale, self.rope_mscale_all_dim,
+        )
 
     @property
     def vocab_padded(self) -> int:
@@ -193,7 +221,7 @@ class ArchConfig:
                         + self.kv_lora_rank * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
                         + self.n_heads * self.v_head_dim * d
                     )
-                ff = 3 * d * self.d_ff_expert * (self.n_experts + self.n_shared_experts)
+                ff = 3 * d * self.d_ff_expert * ((self.n_experts_held or self.n_experts) + self.n_shared_experts)
                 per_layer += count * (attn + ff + d * self.n_experts)
             elif kind == "hymba":
                 dh = self.head_dim
@@ -214,23 +242,26 @@ class ArchConfig:
 # per-segment parameter init                                                   #
 # --------------------------------------------------------------------------- #
 def _init_segment(cfg: ArchConfig, kind: str, count: int, key) -> dict:
-    d, dtype = cfg.d_model, cfg.param_dtype
+    d, dtype, std = cfg.d_model, cfg.param_dtype, cfg.init_std or None
     ks = jax.random.split(key, 6)
     p: dict = {"norm1": jnp.ones((count, d), dtype)}
     if kind in ("dense", "moe", "hymba"):
         if cfg.attn_type == "mla":
             p["attn"] = attn_mod.init_mla(
-                ks[0], count, d, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, dtype
+                ks[0], count, d, cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim,
+                dtype, std,
             )
         else:
             p["attn"] = attn_mod.init_attention(
-                ks[0], count, d, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.qk_norm, dtype
+                ks[0], count, d, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.qk_norm, dtype, std
             )
         p["norm2"] = jnp.ones((count, d), dtype)
     if kind == "dense":
-        p["ffn"] = init_ffn(ks[1], count, d, cfg.d_ff, dtype)
+        p["ffn"] = init_ffn(ks[1], count, d, cfg.d_ff, dtype, std)
     elif kind == "moe":
-        p["moe"] = moe_mod.init_moe(ks[1], count, d, cfg.n_experts, cfg.d_ff_expert, cfg.n_shared_experts, dtype)
+        p["moe"] = moe_mod.init_moe(
+            ks[1], count, d, cfg.n_experts, cfg.d_ff_expert, cfg.n_shared_experts, dtype, cfg.n_experts_held, std
+        )
     elif kind == "hymba":
         p["ssm"] = ssm_mod.init_ssm(ks[2], count, d, cfg.d_inner, cfg.ssm_state, cfg.ssm_conv, dtype=dtype)
         p["ffn"] = init_ffn(ks[1], count, d, cfg.d_ff, dtype)
@@ -267,16 +298,20 @@ def _segment_logical_axes(cfg: ArchConfig, kind: str) -> dict:
 def init_params(cfg: ArchConfig, key) -> dict:
     keys = jax.random.split(key, len(cfg.segments()) + 3)
     params: dict = {
-        "embed": embed_init(keys[0], (cfg.vocab_padded, cfg.d_model), cfg.param_dtype),
+        "embed": embed_init(keys[0], (cfg.vocab_padded, cfg.d_model), cfg.param_dtype, cfg.init_std or 0.02),
         "final_norm": jnp.ones((cfg.d_model,), cfg.param_dtype),
         "segments": [
             _init_segment(cfg, kind, count, keys[i + 1]) for i, (kind, count) in enumerate(cfg.segments())
         ],
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = dense_init(keys[-2], (cfg.d_model, cfg.vocab_padded), dtype=cfg.param_dtype)
+        params["lm_head"] = dense_init(
+            keys[-2], (cfg.d_model, cfg.vocab_padded), dtype=cfg.param_dtype, std=cfg.init_std or None
+        )
     if cfg.frontend == "audio_stub":
-        params["frontend_proj"] = dense_init(keys[-1], (cfg.frontend_dim, cfg.d_model), dtype=cfg.param_dtype)
+        params["frontend_proj"] = dense_init(
+            keys[-1], (cfg.frontend_dim, cfg.d_model), dtype=cfg.param_dtype, std=cfg.init_std or None
+        )
     return params
 
 
@@ -312,6 +347,7 @@ def _apply_attention(cfg: ArchConfig, p_attn: dict, x, positions, cache, update_
             unroll=cfg.unroll_scans,
             cache=cache,
             update_cache=update_cache,
+            yarn=cfg.yarn,
         )
     return attn_mod.apply_attention(
         p_attn,
@@ -332,8 +368,10 @@ def _apply_attention(cfg: ArchConfig, p_attn: dict, x, positions, cache, update_
 
 
 def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, update_cache):
-    """One layer. Returns (x, aux_loss, new_cache)."""
+    """One layer. Returns (x, aux_loss, dropped, new_cache); ``dropped`` counts
+    the expert choices that the capacity bound dropped (0 outside MoE)."""
     aux = jnp.zeros((), jnp.float32)
+    dropped = jnp.zeros((), jnp.int32)
     if kind in ("dense", "moe", "hymba"):
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         if kind == "hymba":
@@ -354,22 +392,23 @@ def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, updat
             new_cache = (new_attn_cache, new_ssm_state) if cache is not None else None
             h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
             x = x + apply_ffn(p["ffn"], h2)
-            return x, aux, new_cache
+            return x, aux, dropped, new_cache
         a_out, new_cache = _apply_attention(cfg, p["attn"], h, positions, cache, update_cache)
         x = x + a_out
         h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
         if kind == "dense":
             x = x + apply_ffn(p["ffn"], h2)
         else:
-            m_out, aux = moe_mod.apply_moe(
+            m_out, aux, dropped = moe_mod.apply_moe(
                 p["moe"],
                 h2,
                 cfg.top_k,
                 capacity_factor=cfg.capacity_factor,
                 group_size=cfg.moe_group_size,
+                renormalize=cfg.norm_topk_prob,
             )
             x = x + m_out
-        return x, aux, new_cache
+        return x, aux, dropped, new_cache
     if kind == "mlstm":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         state, conv_state = cache if cache is not None else (None, None)
@@ -384,11 +423,11 @@ def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, updat
             conv_state=conv_state,
         )
         new_cache = (new_state, new_conv) if cache is not None else None
-        return x + out, aux, new_cache
+        return x + out, aux, dropped, new_cache
     if kind == "slstm":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         out, new_state = _apply_slstm_maybe_sharded(cfg, p["slstm"], h, cache, update_cache)
-        return x + out, aux, new_state if cache is not None else None
+        return x + out, aux, dropped, new_state if cache is not None else None
     raise ValueError(kind)
 
 
@@ -475,7 +514,8 @@ def cast_for_compute(cfg: ArchConfig, params: dict) -> dict:
 
 
 def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_seg, update_cache):
-    """Scan a homogeneous segment of layers; caches are stacked on axis 0."""
+    """Scan a homogeneous segment of layers; caches are stacked on axis 0.
+    Returns (x, aux_loss, dropped, new_caches), aux and dropped summed."""
 
     # Cast params to compute dtype BEFORE the layer scan (§Perf: the per-layer
     # FSDP all-gather then moves bf16, not fp32 — halves weight-gather traffic).
@@ -483,30 +523,29 @@ def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_s
     p_seg = _cast_segment(cfg, p_seg)
 
     def body(carry, xs):
-        x_in, aux_in = carry
+        x_in, aux_in, dropped_in = carry
         p_layer, cache_layer = xs
-        x_out, aux, new_cache = _apply_block(cfg, kind, p_layer, x_in, positions, cache_layer, update_cache)
-        return (x_out, aux_in + aux), new_cache
+        x_out, aux, dropped, new_cache = _apply_block(cfg, kind, p_layer, x_in, positions, cache_layer, update_cache)
+        return (x_out, aux_in + aux, dropped_in + dropped), new_cache
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
+    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
     if cfg.unroll_layers:
         n = jax.tree_util.tree_leaves(p_seg)[0].shape[0]
-        carry = (x, jnp.zeros((), jnp.float32))
         ys = []
         for i in range(n):
             xs_i = jax.tree_util.tree_map(lambda a: a[i], (p_seg, cache_seg))
             carry, y = body(carry, xs_i)
             ys.append(y)
-        (x, aux) = carry
         new_caches = (
             jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ys) if ys and ys[0] is not None else None
         )
-        return x, aux, new_caches
+        return (*carry, new_caches)
 
-    (x, aux), new_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), (p_seg, cache_seg))
-    return x, aux, new_caches
+    carry, new_caches = jax.lax.scan(body, carry, (p_seg, cache_seg))
+    return (*carry, new_caches)
 
 
 # --------------------------------------------------------------------------- #
@@ -534,6 +573,38 @@ def embed_inputs(cfg: ArchConfig, params: dict, batch: Dict[str, Array]) -> Tupl
     return lsc(x, ("batch", "seq", "embed")), positions
 
 
+def forward_layers(
+    cfg: ArchConfig,
+    params: dict,
+    batch: Dict[str, Array],
+    caches: Optional[List[Any]] = None,
+    update_cache: bool = False,
+) -> Tuple[Array, Array, Array, Optional[List[Any]]]:
+    """Every layer, without the final norm and head. Returns (x [B,S,d],
+    aux_loss, dropped expert choices, new_caches)."""
+    x, positions = embed_inputs(cfg, params, batch)
+    aux_total = jnp.zeros((), jnp.float32)
+    dropped_total = jnp.zeros((), jnp.int32)
+    new_caches: List[Any] = []
+    for i, (kind, _count) in enumerate(cfg.segments()):
+        cache_seg = caches[i] if caches is not None else None
+        x, aux, dropped, new_cache_seg = _scan_segment(
+            cfg, kind, params["segments"][i], x, positions, cache_seg, update_cache
+        )
+        aux_total = aux_total + aux
+        dropped_total = dropped_total + dropped
+        new_caches.append(new_cache_seg)
+    return x, aux_total, dropped_total, (new_caches if caches is not None else None)
+
+
+def apply_head(cfg: ArchConfig, params: dict, x: Array) -> Array:
+    """The final norm and the output head: logits [B,S,V_pad] of x [B,S,d]."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+    return lsc(logits, ("batch", "seq", "vocab"))
+
+
 def forward(
     cfg: ArchConfig,
     params: dict,
@@ -542,21 +613,8 @@ def forward(
     update_cache: bool = False,
 ) -> Tuple[Array, Array, Optional[List[Any]]]:
     """Returns (logits [B,S,V_pad], aux_loss, new_caches)."""
-    x, positions = embed_inputs(cfg, params, batch)
-    aux_total = jnp.zeros((), jnp.float32)
-    new_caches: List[Any] = []
-    for i, (kind, _count) in enumerate(cfg.segments()):
-        cache_seg = caches[i] if caches is not None else None
-        x, aux, new_cache_seg = _scan_segment(
-            cfg, kind, params["segments"][i], x, positions, cache_seg, update_cache
-        )
-        aux_total = aux_total + aux
-        new_caches.append(new_cache_seg)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    logits = lsc(logits, ("batch", "seq", "vocab"))
-    return logits, aux_total, (new_caches if caches is not None else None)
+    x, aux, _, new_caches = forward_layers(cfg, params, batch, caches, update_cache)
+    return apply_head(cfg, params, x), aux, new_caches
 
 
 def mask_padded_vocab(cfg: ArchConfig, logits: Array) -> Array:

@@ -12,10 +12,15 @@ token on the host and had its last (:class:`RequestTimes`); once done, it
 publishes its own ``serve.generate_ms``, ``serve.ttft_ms`` and
 ``serve.queue_wait_ms``. Spans (:mod:`repro.telemetry.spans`): ``paio.serve.admit``
 (a drain's admission through the stage), ``paio.serve.prefill`` (one
-request's cache init, prefill and first token pull), ``paio.serve.decode``
+request's cache init, ``paio.serve.cache_init``, then its prefill and first
+token pull), ``paio.serve.decode``
 (one lockstep step: its enforcement, then per live request its decode call,
 ``paio.serve.dispatch``, and its token pull, ``paio.serve.sync``) and
 ``paio.serve.cast_params`` (the weights' one cast to the compute dtype).
+Gauges and counters: ``serve.params_precast_bytes``, ``serve.cache_bytes``
+(one request's caches as allocated) and ``serve.moe_dropped_choices`` (the
+expert choices that the capacity bound dropped in prefills; a decode call of
+one token never drops).
 """
 from __future__ import annotations
 
@@ -195,6 +200,14 @@ class ServeEngine:
             for o in lv.outs
         ]
 
+    def _publish_prefill(self, caches, dropped: int) -> None:
+        """One prefill's bytes of caches as allocated, and the expert choices
+        its capacity bound dropped."""
+        self._registry.set_gauge("serve.cache_bytes", float(sum(a.nbytes for a in jax.tree_util.tree_leaves(caches))))
+        self._describe_once("serve.cache_bytes", "paio_serve_cache_bytes")
+        self._registry.inc("serve.moe_dropped_choices", float(dropped))
+        self._describe_once("serve.moe_dropped_choices", "paio_serve_moe_dropped_choices")
+
     def _publish_step(self, elapsed_s: float) -> None:
         """One decode step's wall time (drain: the lockstep step across all
         live requests; generate: the single request's step)."""
@@ -260,14 +273,17 @@ class ServeEngine:
         b, s0 = p.prompts.shape
         p.times.prefill_start = time.perf_counter()
         with span("paio.serve.prefill", request=p.request_id):
-            caches = init_caches(self.cfg, b, self.max_seq, dtype=self.cfg.compute_dtype)
+            with span("paio.serve.cache_init", request=p.request_id):
+                caches = init_caches(self.cfg, b, self.max_seq, dtype=self.cfg.compute_dtype)
             batch = {
                 "tokens": jnp.asarray(p.prompts, jnp.int32),
                 "positions": jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32), (b, s0)),
             }
-            next_tok, caches = self._prefill(self._compute_params, caches, batch)
-            outs = [[int(t)] for t in np.asarray(next_tok)[:, 0]]
+            next_tok, dropped, caches = self._prefill(self._compute_params, caches, batch)
+            first, dropped = jax.device_get((next_tok, dropped))  # one pull for both
+            outs = [[int(t)] for t in first[:, 0]]
         p.times.first_token = time.perf_counter()
+        self._publish_prefill(caches, int(dropped))
         if p.max_new_tokens <= 1:
             p.times.done = p.times.first_token
         return _Live(p, caches, next_tok, outs, b, s0)

@@ -119,7 +119,7 @@ tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, cfg.vocab)
 
 caches = init_caches(cfg, 8, 32, dtype=jnp.float32)
 prefill = jax.jit(build_prefill_step(cfg))
-tok_ref, caches = prefill(params, caches, {"tokens": tokens})
+tok_ref, _, caches = prefill(params, caches, {"tokens": tokens})
 decode = jax.jit(build_decode_step(cfg))
 tok2_ref, _ = decode(params, caches, {"tokens": tok_ref, "positions": jnp.full((8,1), 16, jnp.int32)})
 
@@ -131,8 +131,8 @@ with mesh, sharding_rules(mesh, rules):
     params_s = jax.device_put(params, pshard)
     caches_s = jax.device_put(init_caches(cfg, 8, 32, dtype=jnp.float32), cshard)
     prefill_s = jax.jit(build_prefill_step(cfg), in_shardings=(pshard, cshard, None),
-                        out_shardings=(None, cshard))
-    tok_s, caches_s = prefill_s(params_s, caches_s, {"tokens": tokens})
+                        out_shardings=(None, None, cshard))
+    tok_s, _, caches_s = prefill_s(params_s, caches_s, {"tokens": tokens})
     decode_s = jax.jit(build_decode_step(cfg), in_shardings=(pshard, cshard, None),
                        out_shardings=(None, cshard))
     tok2_s, _ = decode_s(params_s, caches_s, {"tokens": tok_s, "positions": jnp.full((8,1), 16, jnp.int32)})

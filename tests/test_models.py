@@ -122,7 +122,7 @@ class TestMoE:
         p = jax.tree_util.tree_map(lambda a: a[0] if False else a, init_moe(jax.random.PRNGKey(0), 1, d, e, 32))
         p1 = jax.tree_util.tree_map(lambda a: a[0], p)  # layer slice
         x = _rand(20, (2, 32, d))
-        out, aux = apply_moe(p1, x, k, capacity_factor=8.0, group_size=16)
+        out, aux, _ = apply_moe(p1, x, k, capacity_factor=8.0, group_size=16)
         assert out.shape == x.shape
         assert np.isfinite(np.array(out)).all() and np.isfinite(float(aux))
         # aux loss lower bound: balanced routing gives e/k * k/e... ≈ 1
@@ -134,8 +134,8 @@ class TestMoE:
         d, e, k = 16, 4, 2
         p1 = jax.tree_util.tree_map(lambda a: a[0], init_moe(jax.random.PRNGKey(0), 1, d, e, 32))
         x = _rand(21, (2, 32, d))
-        out_hi, _ = apply_moe(p1, x, k, capacity_factor=8.0, group_size=16)
-        out_lo, _ = apply_moe(p1, x, k, capacity_factor=0.5, group_size=16)
+        out_hi, _, _ = apply_moe(p1, x, k, capacity_factor=8.0, group_size=16)
+        out_lo, _, _ = apply_moe(p1, x, k, capacity_factor=0.5, group_size=16)
         # low capacity drops tokens (outputs differ) but stays finite
         assert np.isfinite(np.array(out_lo)).all()
         assert not np.allclose(np.array(out_hi), np.array(out_lo))

@@ -37,7 +37,7 @@ def float32_tree_tokens(cfg, params, prompts, n_new):
     b, s0 = prompts.shape
     caches = init_caches(cfg, b, MAX_SEQ, dtype=cfg.compute_dtype)
     positions = jnp.broadcast_to(jnp.arange(s0, dtype=jnp.int32), (b, s0))
-    tok, caches = prefill(params, caches, {"tokens": jnp.asarray(prompts), "positions": positions})
+    tok, _, caches = prefill(params, caches, {"tokens": jnp.asarray(prompts), "positions": positions})
     out = [np.asarray(tok)[:, 0]]
     for step in range(1, n_new):
         pos = jnp.full((b, 1), s0 + step - 1, jnp.int32)
