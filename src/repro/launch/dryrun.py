@@ -94,14 +94,11 @@ def _lower_cell(cfg, cell, mesh, rules, microbatches: int, unroll_micro: bool = 
             lambda: model_lib.init_caches(cfg, cell.global_batch, cell.seq, dtype=jnp.bfloat16)
         )
         cache_shardings = make_cache_shardings(cfg, mesh, rules)
-        if cell.kind == "decode":
-            step, out_shardings = build_decode_step(cfg), (None, cache_shardings)
-        else:
-            step, out_shardings = build_prefill_step(cfg), (None, None, cache_shardings)
+        step = build_decode_step(cfg) if cell.kind == "decode" else build_prefill_step(cfg)
         jitted = jax.jit(
             step,
             in_shardings=(param_shardings, cache_shardings, batch_shardings),
-            out_shardings=out_shardings,
+            out_shardings=(None, None, cache_shardings),
             donate_argnums=1,
         )
         return jitted.lower(abstract_params, abstract_caches, batch_specs)

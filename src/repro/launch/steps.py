@@ -179,28 +179,32 @@ def build_train_step(cfg: ArchConfig, tcfg: TrainConfig = TrainConfig()) -> Call
 # --------------------------------------------------------------------------- #
 # serve steps                                                                  #
 # --------------------------------------------------------------------------- #
-def build_prefill_step(cfg: ArchConfig) -> Callable:
-    """(params, caches, batch) → (next_token [B,1], dropped, caches): the head
-    runs on the last position only; ``dropped`` is the expert choices that the
-    capacity bound dropped, summed over layers (0 without MoE)."""
+def _serve_step(cfg: ArchConfig, count: int) -> Callable:
+    """(params, caches, batch) → (next_token [B,1], counts[count], caches),
+    ``counts`` as ``forward_layers`` returns them; the head runs on the last
+    position only."""
 
-    def prefill(params, caches, batch):
-        x, _, dropped, new_caches = model_lib.forward_layers(cfg, params, batch, caches=caches, update_cache=True)
+    def step(params, caches, batch):
+        x, _, counts, new_caches = model_lib.forward_layers(cfg, params, batch, caches=caches, update_cache=True)
         logits = model_lib.mask_padded_vocab(cfg, model_lib.apply_head(cfg, params, x[:, -1:]))
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_token, dropped, new_caches
+        return next_token, counts[count], new_caches
 
-    return prefill
+    return step
+
+
+def build_prefill_step(cfg: ArchConfig) -> Callable:
+    """(params, caches, batch) → (next_token [B,1], dropped, caches):
+    ``dropped`` is the expert choices that the capacity bound dropped, summed
+    over layers (0 without MoE)."""
+    return _serve_step(cfg, 0)
 
 
 def build_decode_step(cfg: ArchConfig) -> Callable:
-    def decode(params, caches, batch):
-        logits, _, new_caches = model_lib.forward(cfg, params, batch, caches=caches, update_cache=True)
-        logits = model_lib.mask_padded_vocab(cfg, logits)
-        next_token = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        return next_token, new_caches
-
-    return decode
+    """(params, caches, batch) → (next_token [B,1], experts_run, caches):
+    ``experts_run`` is the held experts whose FFN ran, summed over layers (0
+    without MoE)."""
+    return _serve_step(cfg, 1)
 
 
 def build_encoder_step(cfg: ArchConfig) -> Callable:

@@ -367,11 +367,13 @@ def _apply_attention(cfg: ArchConfig, p_attn: dict, x, positions, cache, update_
     )
 
 
-def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, update_cache):
-    """One layer. Returns (x, aux_loss, dropped, new_cache); ``dropped`` counts
-    the expert choices that the capacity bound dropped (0 outside MoE)."""
+def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, update_cache, layer=None):
+    """One layer. Returns (x, aux_loss, counts, new_cache); ``counts`` is
+    (dropped, ran): the expert choices that the capacity bound dropped and the
+    held experts whose FFN ran (both 0 outside MoE). ``layer``: as
+    ``moe.apply_moe_counted`` takes it."""
     aux = jnp.zeros((), jnp.float32)
-    dropped = jnp.zeros((), jnp.int32)
+    counts = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     if kind in ("dense", "moe", "hymba"):
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         if kind == "hymba":
@@ -392,23 +394,25 @@ def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, updat
             new_cache = (new_attn_cache, new_ssm_state) if cache is not None else None
             h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
             x = x + apply_ffn(p["ffn"], h2)
-            return x, aux, dropped, new_cache
+            return x, aux, counts, new_cache
         a_out, new_cache = _apply_attention(cfg, p["attn"], h, positions, cache, update_cache)
         x = x + a_out
         h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
         if kind == "dense":
             x = x + apply_ffn(p["ffn"], h2)
         else:
-            m_out, aux, dropped = moe_mod.apply_moe(
+            m_out, aux, dropped, ran = moe_mod.apply_moe_counted(
                 p["moe"],
                 h2,
                 cfg.top_k,
                 capacity_factor=cfg.capacity_factor,
                 group_size=cfg.moe_group_size,
                 renormalize=cfg.norm_topk_prob,
+                layer=layer,
             )
+            counts = (dropped, ran)
             x = x + m_out
-        return x, aux, dropped, new_cache
+        return x, aux, counts, new_cache
     if kind == "mlstm":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         state, conv_state = cache if cache is not None else (None, None)
@@ -423,11 +427,11 @@ def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, updat
             conv_state=conv_state,
         )
         new_cache = (new_state, new_conv) if cache is not None else None
-        return x + out, aux, dropped, new_cache
+        return x + out, aux, counts, new_cache
     if kind == "slstm":
         h = rms_norm(x, p["norm1"], cfg.norm_eps)
         out, new_state = _apply_slstm_maybe_sharded(cfg, p["slstm"], h, cache, update_cache)
-        return x + out, aux, dropped, new_state if cache is not None else None
+        return x + out, aux, counts, new_state if cache is not None else None
     raise ValueError(kind)
 
 
@@ -515,23 +519,37 @@ def cast_for_compute(cfg: ArchConfig, params: dict) -> dict:
 
 def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_seg, update_cache):
     """Scan a homogeneous segment of layers; caches are stacked on axis 0.
-    Returns (x, aux_loss, dropped, new_caches), aux and dropped summed."""
+    Returns (x, aux_loss, counts, new_caches), aux and counts summed."""
 
     # Cast params to compute dtype BEFORE the layer scan (§Perf: the per-layer
     # FSDP all-gather then moves bf16, not fp32 — halves weight-gather traffic).
     # A tree from ``cast_for_compute`` arrives cast, and this casts nothing.
     p_seg = _cast_segment(cfg, p_seg)
+    stacks = None
+    if kind == "moe" and moe_mod.decode_sized(x.shape[0] * x.shape[1]):
+        # the experts' stacks stay whole, out of the scan, and the expert loop
+        # reads a chosen expert at (layer, expert): handed a layer's slice, it
+        # would copy every held expert of the layer first
+        moe_p = dict(p_seg["moe"])
+        stacks = {n: moe_p.pop(n) for n in moe_mod.EXPERT_WEIGHTS}
+        p_seg = {**p_seg, "moe": moe_p}
 
     def body(carry, xs):
-        x_in, aux_in, dropped_in = carry
+        x_in, aux_in, counts_in, layer = carry
         p_layer, cache_layer = xs
-        x_out, aux, dropped, new_cache = _apply_block(cfg, kind, p_layer, x_in, positions, cache_layer, update_cache)
-        return (x_out, aux_in + aux, dropped_in + dropped), new_cache
+        if stacks is not None:
+            p_layer = {**p_layer, "moe": {**p_layer["moe"], **stacks}}
+        x_out, aux, counts, new_cache = _apply_block(
+            cfg, kind, p_layer, x_in, positions, cache_layer, update_cache, layer if stacks is not None else None
+        )
+        counts = tuple(a + b for a, b in zip(counts_in, counts))
+        return (x_out, aux_in + aux, counts, layer + 1), new_cache
 
     if cfg.remat:
         body = jax.checkpoint(body, prevent_cse=False)
 
-    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
+    zero = jnp.zeros((), jnp.int32)
+    carry = (x, jnp.zeros((), jnp.float32), (zero, zero), zero)
     if cfg.unroll_layers:
         n = jax.tree_util.tree_leaves(p_seg)[0].shape[0]
         ys = []
@@ -542,10 +560,10 @@ def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_s
         new_caches = (
             jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ys) if ys and ys[0] is not None else None
         )
-        return (*carry, new_caches)
+        return (*carry[:3], new_caches)
 
     carry, new_caches = jax.lax.scan(body, carry, (p_seg, cache_seg))
-    return (*carry, new_caches)
+    return (*carry[:3], new_caches)
 
 
 # --------------------------------------------------------------------------- #
@@ -581,20 +599,20 @@ def forward_layers(
     update_cache: bool = False,
 ) -> Tuple[Array, Array, Array, Optional[List[Any]]]:
     """Every layer, without the final norm and head. Returns (x [B,S,d],
-    aux_loss, dropped expert choices, new_caches)."""
+    aux_loss, (dropped expert choices, held experts run), new_caches)."""
     x, positions = embed_inputs(cfg, params, batch)
     aux_total = jnp.zeros((), jnp.float32)
-    dropped_total = jnp.zeros((), jnp.int32)
+    counts_total = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     new_caches: List[Any] = []
     for i, (kind, _count) in enumerate(cfg.segments()):
         cache_seg = caches[i] if caches is not None else None
-        x, aux, dropped, new_cache_seg = _scan_segment(
+        x, aux, counts, new_cache_seg = _scan_segment(
             cfg, kind, params["segments"][i], x, positions, cache_seg, update_cache
         )
         aux_total = aux_total + aux
-        dropped_total = dropped_total + dropped
+        counts_total = tuple(a + b for a, b in zip(counts_total, counts))
         new_caches.append(new_cache_seg)
-    return x, aux_total, dropped_total, (new_caches if caches is not None else None)
+    return x, aux_total, counts_total, (new_caches if caches is not None else None)
 
 
 def apply_head(cfg: ArchConfig, params: dict, x: Array) -> Array:

@@ -18,9 +18,10 @@ token pull), ``paio.serve.decode``
 ``paio.serve.dispatch``, and its token pull, ``paio.serve.sync``) and
 ``paio.serve.cast_params`` (the weights' one cast to the compute dtype).
 Gauges and counters: ``serve.params_precast_bytes``, ``serve.cache_bytes``
-(one request's caches as allocated) and ``serve.moe_dropped_choices`` (the
+(one request's caches as allocated), ``serve.moe_dropped_choices`` (the
 expert choices that the capacity bound dropped in prefills; a decode call of
-one token never drops).
+one token never drops) and ``serve.moe_decode_experts_run`` (the held experts
+whose FFN ran in decode calls, summed over layers).
 """
 from __future__ import annotations
 
@@ -294,12 +295,14 @@ class ServeEngine:
         rid = lv.pending.request_id
         pos = jnp.full((lv.batch, 1), lv.prompt_len + step - 1, jnp.int32)
         with span("paio.serve.dispatch", request=rid):
-            lv.next_tok, lv.caches = self._decode(
+            lv.next_tok, ran, lv.caches = self._decode(
                 self._compute_params, lv.caches, {"tokens": lv.next_tok, "positions": pos}
             )
         with span("paio.serve.sync", request=rid):
-            tokens = np.asarray(lv.next_tok)[:, 0]
-        for i, t in enumerate(tokens):
+            tokens, ran = jax.device_get((lv.next_tok, ran))  # one pull for both
+        self._registry.inc("serve.moe_decode_experts_run", float(ran))
+        self._describe_once("serve.moe_decode_experts_run", "paio_serve_moe_decode_experts_run")
+        for i, t in enumerate(tokens[:, 0]):
             lv.outs[i].append(int(t))
         if step + 1 >= lv.pending.max_new_tokens:
             lv.pending.times.done = time.perf_counter()

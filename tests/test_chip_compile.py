@@ -116,3 +116,32 @@ def test_flash_attention_compiles(one_chip, no_persistent_cache, heads, kv_heads
         _spec((b, s, kv_heads, head_dim), jnp.bfloat16),
     )
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_moe_decode_makes_no_copy_of_a_layers_held_experts(one_chip, no_persistent_cache):
+    """The decode step at DeepSeek-V2-Lite's widths (its dense layer and four
+    MoE layers of 8 held experts) reads each chosen expert where its segment's
+    stack holds it: no instruction makes an array of one layer's held experts
+    (``[8, 2048, 1408]`` or ``[8, 1408, 2048]``), as a layer's slice handed
+    whole to the expert FFN would."""
+    import json
+    import re
+
+    from repro.launch.steps import build_decode_step
+    from repro.models import init_caches, init_params
+    from repro.models.model import ArchConfig, cast_for_compute
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs", "deepseek-v2-lite.json")) as f:
+        cfg = ArchConfig(**{**json.load(f)["arch"], "n_layers": 5})
+    params = jax.eval_shape(lambda: cast_for_compute(cfg, init_params(cfg, jax.random.PRNGKey(0))))
+    caches = jax.eval_shape(lambda: init_caches(cfg, 1, 256, dtype=cfg.compute_dtype))
+    batch = {"tokens": _spec((1, 1), jnp.int32), "positions": _spec((1, 1), jnp.int32)}
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), (params, caches, batch)
+    )
+    text = jax.jit(build_decode_step(cfg)).lower(*args).compile().as_text()
+    held, d, f = cfg.n_experts_held, cfg.d_model, cfg.d_ff_expert
+    stacks = {f"bf16[{held},{d},{f}]", f"bf16[{held},{f},{d}]", f"bf16[1,{held},{d},{f}]", f"bf16[1,{held},{f},{d}]"}
+    assert f"bf16[4,{held},{d},{f}]" in text  # the segment's stack is there
+    assert not stacks & set(re.findall(r"= (bf16\[[\d,]+\])", text))

@@ -121,7 +121,7 @@ caches = init_caches(cfg, 8, 32, dtype=jnp.float32)
 prefill = jax.jit(build_prefill_step(cfg))
 tok_ref, _, caches = prefill(params, caches, {"tokens": tokens})
 decode = jax.jit(build_decode_step(cfg))
-tok2_ref, _ = decode(params, caches, {"tokens": tok_ref, "positions": jnp.full((8,1), 16, jnp.int32)})
+tok2_ref, _, _ = decode(params, caches, {"tokens": tok_ref, "positions": jnp.full((8,1), 16, jnp.int32)})
 
 mesh = make_mesh((4, 2))
 rules = rules_for(cfg, decode=True, batch_size=8, mesh=mesh)
@@ -134,8 +134,8 @@ with mesh, sharding_rules(mesh, rules):
                         out_shardings=(None, None, cshard))
     tok_s, _, caches_s = prefill_s(params_s, caches_s, {"tokens": tokens})
     decode_s = jax.jit(build_decode_step(cfg), in_shardings=(pshard, cshard, None),
-                       out_shardings=(None, cshard))
-    tok2_s, _ = decode_s(params_s, caches_s, {"tokens": tok_s, "positions": jnp.full((8,1), 16, jnp.int32)})
+                       out_shardings=(None, None, cshard))
+    tok2_s, _, _ = decode_s(params_s, caches_s, {"tokens": tok_s, "positions": jnp.full((8,1), 16, jnp.int32)})
 
 assert np.array_equal(np.array(tok_ref), np.array(jax.device_get(tok_s)))
 assert np.array_equal(np.array(tok2_ref), np.array(jax.device_get(tok2_s)))

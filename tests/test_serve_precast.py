@@ -41,7 +41,7 @@ def float32_tree_tokens(cfg, params, prompts, n_new):
     out = [np.asarray(tok)[:, 0]]
     for step in range(1, n_new):
         pos = jnp.full((b, 1), s0 + step - 1, jnp.int32)
-        tok, caches = decode(params, caches, {"tokens": tok, "positions": pos})
+        tok, _, caches = decode(params, caches, {"tokens": tok, "positions": pos})
         out.append(np.asarray(tok)[:, 0])
     return np.stack(out, axis=1)
 
