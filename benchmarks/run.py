@@ -7,16 +7,10 @@ approaches the paper's durations.
   profile_*     per-op latencies (§6.1 profiling paragraph)
   fig5_7_*      tail-latency control (Figs 5–7, Algorithm 1)
   fig8_*        per-application bandwidth guarantees (Fig 8, Algorithm 2)
-  kernel_*      Pallas kernel interpret-mode sanity timings (CPU)
-  roofline_*    dry-run derived terms (reads experiments/dryrun JSONs)
 """
 from __future__ import annotations
 
 import argparse
-import glob
-import json
-import os
-import time
 
 
 def emit(name: str, us_per_call: float, derived: str = "") -> None:
@@ -98,46 +92,6 @@ def bench_fig8(scale: float) -> None:
         emit(f"fig8_{mode}", makespan * 1e6, f"guarantees={'met' if met else 'VIOLATED'} makespan={makespan:.1f}s")
 
 
-def bench_kernels() -> None:
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.flash_attention.ops import flash_attention
-    from repro.kernels.quantize.ops import dequantize_int8, quantize_int8
-
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (1, 128, 4, 64), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 128, 2, 64), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 128, 2, 64), jnp.float32)
-    t0 = time.perf_counter()
-    flash_attention(q, k, v, causal=True, interpret=True).block_until_ready()
-    emit("kernel_flash_attention_interpret", (time.perf_counter() - t0) * 1e6, "GQA 128x128 d64")
-
-    x = jax.random.normal(ks[0], (512, 512), jnp.float32)
-    t0 = time.perf_counter()
-    qq, s, meta = quantize_int8(x, interpret=True)
-    dequantize_int8(qq, s, meta, interpret=True).block_until_ready()
-    emit("kernel_quantize_roundtrip_interpret", (time.perf_counter() - t0) * 1e6, "512x512 int8")
-
-
-def bench_roofline() -> None:
-    files = sorted(glob.glob("experiments/dryrun/*_pod.json"))
-    if not files:
-        emit("roofline_missing", 0.0, "run: python -m repro.launch.dryrun --all")
-        return
-    for f in files:
-        r = json.load(open(f))
-        rf = r.get("roofline", {})
-        name = os.path.basename(f)[:-5]
-        step_s = max(rf.get("compute_s", 0), rf.get("memory_s", 0), rf.get("collective_s", 0))
-        emit(
-            f"roofline_{name}",
-            step_s * 1e6,
-            f"dominant={rf.get('dominant')} useful={rf.get('useful_flops_ratio', 0):.2f} "
-            f"mem/dev={r.get('memory_per_device_gib', 0):.1f}GiB",
-        )
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
@@ -145,7 +99,7 @@ def main() -> None:
         "--smoke", action="store_true", help="~2s loopback bench only (per-PR CI perf signal)"
     )
     ap.add_argument(
-        "--skip", default="", help="comma list: fig4,batch,policy,fig5_7,fig8,kernels,roofline"
+        "--skip", default="", help="comma list: fig4,batch,policy,fig5_7,fig8"
     )
     args = ap.parse_args()
     skip = set(args.skip.split(",")) if args.skip else set()
@@ -164,10 +118,6 @@ def main() -> None:
         bench_fig5_7(seconds=20.0 if args.full else 6.0)
     if "fig8" not in skip:
         bench_fig8(scale=0.25 if args.full else 0.1)
-    if "kernels" not in skip:
-        bench_kernels()
-    if "roofline" not in skip:
-        bench_roofline()
 
 
 if __name__ == "__main__":

@@ -2,8 +2,8 @@
 
 48L d_model=1280 16H d_ff=5120 vocab=504 — bidirectional encoder-only;
 the conv waveform frontend is a STUB (``input_specs`` provides precomputed
-512-d frame embeddings). No decode shapes (encoder has no autoregressive
-step) — recorded in DESIGN.md.
+512-d frame embeddings). No decode or serve path: an encoder has no
+autoregressive step.
 """
 from repro.models.model import ArchConfig
 

@@ -2,9 +2,9 @@
 
 32L d_model=1600 25H (GQA kv=5) d_ff=5504, parallel attention + Mamba heads
 per block, ssm_state=16. Attention uses sliding-window (1024) — Hymba keeps
-3 global-attention layers; we use the SWA form uniformly (noted in
-DESIGN.md), which is also what makes ``long_500k`` decode sub-quadratic with
-a window-bounded KV cache.
+3 global-attention layers; we use the SWA form uniformly (a departure from the
+published model), which keeps decode sub-quadratic with a window-bounded KV
+cache at any length.
 """
 from repro.models.model import ArchConfig
 

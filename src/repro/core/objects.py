@@ -477,9 +477,9 @@ def _process_runs_jax_on_tpu() -> bool:
 class QuantizeInt8(EnforcementObject):
     """Host-side int8 symmetric per-block quantization transformation.
 
-    The device-side twin (Pallas kernel, ``repro.kernels.quantize``) runs on
-    TPU for gradient compression; this object serves the checkpoint write
-    path. Block size is per-row groups of ``block`` elements.
+    The device-side twin is the Pallas kernel in ``repro.kernels.quantize``;
+    this object serves the checkpoint write path. Block size is per-row
+    groups of ``block`` elements.
 
     ``obj_enf_batch`` packs the whole batch into one ``[M, block]`` matrix and
     quantizes it with a single fused call — the Pallas rows kernel, else one

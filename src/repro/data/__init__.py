@@ -1,3 +1,3 @@
-from .pipeline import DataPipeline, FileTokenSource, SyntheticTokenSource, make_batch_specs
+from .pipeline import DataPipeline, FileTokenSource, SyntheticTokenSource
 
-__all__ = ["DataPipeline", "FileTokenSource", "SyntheticTokenSource", "make_batch_specs"]
+__all__ = ["DataPipeline", "FileTokenSource", "SyntheticTokenSource"]

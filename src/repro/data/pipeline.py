@@ -15,15 +15,11 @@ from __future__ import annotations
 import os
 import queue
 import threading
-from typing import Dict, Iterator, Optional
+from typing import Iterator, Optional
 
 import numpy as np
 
-import jax
-import jax.numpy as jnp
-
 from repro.core import FG_FETCH, ArrayInstance, RequestType, Stage, propagate_context
-from repro.models.model import ArchConfig
 from repro.telemetry.spans import span
 
 
@@ -178,31 +174,3 @@ class DataPipeline:
                     break
             self._thread.join(timeout=5.0)
             self._thread = None
-
-
-# --------------------------------------------------------------------------- #
-# batch specs per (arch × shape cell) — shared by dry-run and training         #
-# --------------------------------------------------------------------------- #
-def make_batch_specs(cfg: ArchConfig, batch: int, seq: int, kind: str) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for every model input of one step.
-
-    ``kind``: ``train`` (full-seq batch), ``prefill`` (full-seq serve),
-    ``decode`` (one token against a ``seq``-long cache — token specs only;
-    cache specs come from ``models.init_caches`` via ``eval_shape``).
-    """
-    f32, i32 = jnp.float32, jnp.int32
-    s_tok = 1 if kind == "decode" else seq
-    specs: Dict[str, jax.ShapeDtypeStruct] = {}
-    if cfg.family == "audio":
-        specs["frames"] = jax.ShapeDtypeStruct((batch, s_tok, cfg.frontend_dim), f32)
-        if kind == "train":
-            specs["labels"] = jax.ShapeDtypeStruct((batch, s_tok), i32)
-        return specs
-    if cfg.family == "vlm":
-        if kind != "decode":
-            specs["vision_embeds"] = jax.ShapeDtypeStruct((batch, cfg.n_vision_tokens, cfg.d_model), f32)
-            s_tok = max(s_tok - cfg.n_vision_tokens, 1)  # total seq budget includes vision tokens
-    specs["tokens"] = jax.ShapeDtypeStruct((batch, s_tok), i32)
-    if kind == "decode":
-        specs["positions"] = jax.ShapeDtypeStruct((batch, 1), i32)
-    return specs

@@ -324,11 +324,12 @@ class ShardRouter:
     def enforce_batch(
         self, ctxs: Sequence[Context], requests: Optional[Sequence[Any]] = None
     ) -> List[Result]:
-        """Split-by-shard enforce: group by flow, place, one frame per shard.
+        """Split-by-shard enforce: group by flow and size, place each group by
+        its flow, one frame per shard.
 
         Returns one :class:`Result` per request, echoing the request payload —
         payload bytes never cross the socket; the wire carries only the
-        per-flow group records (ROADMAP: "only control frames need the
+        group records (ROADMAP: "only control frames need the
         socket"). Admission waits happen shard-side; this call returns when
         every shard has admitted its groups. On a shard failure mid-dispatch
         the failed groups re-home to their new HRW owners within this call.
@@ -337,11 +338,12 @@ class ShardRouter:
         if n == 0:
             return []
         self._maybe_probe()
-        # group the batch by flow (one wire record per flow, not per request)
+        # group the batch by flow and size (one wire record per group, not per
+        # request): a record carries one size for all of its ``count`` requests
         counts: Dict[Tuple, int] = {}
         exemplar: Dict[Tuple, Context] = {}
         for ctx in ctxs:
-            key = flow_key(ctx)
+            key = (flow_key(ctx), ctx.size)
             if key in counts:
                 counts[key] += 1
             else:
@@ -349,7 +351,7 @@ class ShardRouter:
                 exemplar[key] = ctx
         flows = list(counts)
         tokens = {key: flow_token(exemplar[key]) for key in flows}
-        # pending: flow key → group record; re-homed flows re-enter here
+        # pending: group key → group record; re-homed groups re-enter here
         pending: Dict[Tuple, Tuple] = {}
         for key in flows:
             c = exemplar[key]

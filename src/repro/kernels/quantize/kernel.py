@@ -1,8 +1,8 @@
 """Int8 block-quantization Pallas kernels.
 
 The device-side twin of PAIO's data-transformation enforcement object
-(paper §3.1): used by the compressed all-reduce (gradient compression with
-error feedback) and by quantized checkpoint shards.
+(paper §3.1): ``QuantizeInt8``'s batch path runs it on a TPU for quantized
+checkpoint shards.
 
 Each (block_r × block_c) tile gets one fp32 scale = absmax/127 — tiles are
 (128, 128) by default so rows/lanes align with the VPU/MXU layout and one

@@ -7,14 +7,12 @@ serving-side functions operating on stacked per-segment caches.
 
 ``make_*_shardings`` translate the model's logical-axis trees into
 ``NamedSharding`` trees for a given mesh — the glue between model code and
-``jax.jit(in_shardings=…)`` used by both the launcher and the multi-pod
-dry-run.
+``jax.jit(in_shardings=…)``.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +46,7 @@ def rules_for(cfg: ArchConfig, *, decode: bool = False, batch_size: Optional[int
         for ax in ("pod", "data"):
             if ax in mesh.axis_names:
                 dp *= mesh.shape[ax]
-        if batch_size % dp != 0:  # e.g. long_500k batch=1
+        if batch_size % dp != 0:  # e.g. batch 1 over a wider data axis
             rules["batch"] = None
             rules["expert_group"] = None
     return rules
@@ -105,12 +103,6 @@ class TrainConfig:
     microbatches: int = 1
     opt: AdamWConfig = AdamWConfig()
     lr_schedule: Optional[Callable] = None
-    #: unroll the microbatch loop (cost probes — while bodies are counted once
-    #: by XLA cost analysis, so probes difference unrolled variants)
-    unroll_micro: bool = False
-    #: compute grads + grad_norm but skip the optimizer update (cost probes
-    #: separate per-layer gradient cost from per-layer optimizer cost)
-    grad_only: bool = False
 
 
 def init_train_state(cfg: ArchConfig, key) -> Dict[str, PyTree]:
@@ -132,7 +124,7 @@ def build_train_step(cfg: ArchConfig, tcfg: TrainConfig = TrainConfig()) -> Call
         params = state["params"]
         n_micro = tcfg.microbatches
         if n_micro <= 1:
-            (loss, metrics), grads = grad_fn(params, batch)
+            (loss, _), grads = grad_fn(params, batch)
         else:
 
             def split(x):
@@ -151,21 +143,10 @@ def build_train_step(cfg: ArchConfig, tcfg: TrainConfig = TrainConfig()) -> Call
 
             g0 = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
             carry0 = (g0, jnp.zeros((), jnp.float32))
-            if tcfg.unroll_micro:
-                carry = carry0
-                for i in range(n_micro):
-                    carry, _ = acc(carry, jax.tree_util.tree_map(lambda a: a[i], micro))
-                grads, loss_sum = carry
-            else:
-                (grads, loss_sum), _ = jax.lax.scan(acc, carry0, micro)
+            (grads, loss_sum), _ = jax.lax.scan(acc, carry0, micro)
             grads = jax.tree_util.tree_map(lambda g: g / n_micro, grads)
             loss = loss_sum / n_micro
-            metrics = {"loss": loss}
 
-        if tcfg.grad_only:
-            from repro.optim import global_norm
-
-            return state, {"loss": loss, "grad_norm": global_norm(grads)}
         lr = tcfg.lr_schedule(state["opt"]["step"]) if tcfg.lr_schedule else None
         new_params, new_opt, opt_metrics = adamw_update(params, grads, state["opt"], tcfg.opt, lr)
         out_metrics = {"loss": loss, **opt_metrics}
@@ -206,12 +187,3 @@ def build_decode_step(cfg: ArchConfig) -> Callable:
     without MoE)."""
     return _serve_step(cfg, 1)
 
-
-def build_encoder_step(cfg: ArchConfig) -> Callable:
-    """Encoder-only inference (hubert): frames → frame logits."""
-
-    def encode(params, batch):
-        logits, _, _ = model_lib.forward(cfg, params, batch)
-        return model_lib.mask_padded_vocab(cfg, logits)
-
-    return encode

@@ -4,8 +4,8 @@ Three execution backends for the softmax-attention core:
 
 * ``xla``     — naive einsum attention (reference; smoke tests),
 * ``chunked`` — online-softmax over KV chunks via ``lax.scan`` (flash-attention
-  recurrence in pure JAX; bounded memory, used for 32k prefill and the
-  multi-pod dry-run),
+  recurrence in pure JAX; bounded memory, used for long-context training and
+  prefill),
 * ``pallas``  — the Pallas TPU kernel in ``repro.kernels.flash_attention``
   (TPU target; validated in interpret mode on CPU).
 
@@ -73,7 +73,6 @@ def attn_core_chunked(
     mask: "MaskSpec",
     scale: float,
     chunk: int = 1024,
-    unroll: bool = False,
 ) -> Array:
     """Online-softmax (flash) recurrence over KV chunks; O(Sq·chunk) scores.
 
@@ -124,13 +123,7 @@ def attn_core_chunked(
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
     acc0 = jnp.zeros((b, h, sq, dv), jnp.float32)
-    if unroll:
-        carry = (m0, l0, acc0)
-        for i in range(n_chunks):
-            carry, _ = step(carry, (kc[i], vc[i], kpc[i], kvc[i]))
-        m, l, acc = carry
-    else:
-        (m, l, acc), _ = jax.lax.scan(step, (m0, l0, acc0), (kc, vc, kpc, kvc))
+    (m, l, acc), _ = jax.lax.scan(step, (m0, l0, acc0), (kc, vc, kpc, kvc))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(v.dtype)  # [B,Sq,H,dv]
 
@@ -151,9 +144,9 @@ class MaskSpec:
         return make_bias(self.q_pos, self.k_pos, self.causal, self.sliding_window, self.k_valid)
 
 
-def attn_core(q, k, v, mask: MaskSpec, scale, backend: str = "xla", chunk: int = 1024, unroll: bool = False) -> Array:
+def attn_core(q, k, v, mask: MaskSpec, scale, backend: str = "xla", chunk: int = 1024) -> Array:
     if backend == "chunked":
-        return attn_core_chunked(q, k, v, mask, scale, chunk=chunk, unroll=unroll)
+        return attn_core_chunked(q, k, v, mask, scale, chunk=chunk)
     if backend == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
 
@@ -221,7 +214,6 @@ def apply_attention(
     norm_eps: float = 1e-5,
     backend: str = "xla",
     chunk: int = 1024,
-    unroll: bool = False,
     cache: Optional[KVCache] = None,
     update_cache: bool = False,
 ) -> Tuple[Array, Optional[KVCache]]:
@@ -286,7 +278,7 @@ def apply_attention(
 
     k_full = repeat_kv(k_att, n_heads // n_kv)
     v_full = repeat_kv(v_att, n_heads // n_kv)
-    out = attn_core(q, k_full, v_full, mask, scale, backend=backend, chunk=chunk, unroll=unroll)
+    out = attn_core(q, k_full, v_full, mask, scale, backend=backend, chunk=chunk)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return lsc(out, ("batch", "seq", "embed")), new_cache
 
@@ -375,7 +367,6 @@ def apply_mla(
     norm_eps: float = 1e-5,
     backend: str = "xla",
     chunk: int = 1024,
-    unroll: bool = False,
     cache: Optional[MLACache] = None,
     update_cache: bool = False,
     yarn: Optional[Yarn] = None,
@@ -434,7 +425,7 @@ def apply_mla(
         q_all = lsc(q_all, ("batch", "seq", "heads", None))
         k_all = lsc(k_all, ("batch", "seq", "heads", None))
         mask = MaskSpec(positions, positions, True, 0)
-        out_h = attn_core(q_all, k_all, value, mask, scale, backend=backend, chunk=chunk, unroll=unroll)
+        out_h = attn_core(q_all, k_all, value, mask, scale, backend=backend, chunk=chunk)
 
     out = jnp.einsum("bshv,hvd->bsd", out_h, p["wo"])
     return lsc(out, ("batch", "seq", "embed")), new_cache

@@ -3,7 +3,7 @@
 Everything is functional: params are plain dicts of ``jnp`` arrays, layers are
 ``init_*``/``apply`` function pairs. Per-layer parameters are *stacked* along a
 leading layer axis so the model can ``lax.scan`` over layers (small HLO, fast
-multi-pod compiles, natural remat boundary).
+compiles, natural remat boundary).
 """
 from __future__ import annotations
 

@@ -2,7 +2,7 @@
 
 One functional Model covers six families (dense / moe / audio / hybrid / ssm /
 vlm) by composing blocks into homogeneous *segments* that are scanned with
-``lax.scan`` (stacked per-layer params → small HLO, fast multi-pod compiles,
+``lax.scan`` (stacked per-layer params → small HLO, fast compiles,
 natural remat boundary):
 
 * ``dense``  — pre-norm attention (GQA/MLA variants) + SwiGLU FFN
@@ -85,6 +85,7 @@ class ArchConfig:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_expand: int = 2
+    ssm_chunk: int = 2048
     # xLSTM
     slstm_every: int = 0  # every k-th block is sLSTM (0 = none)
     mlstm_expand: int = 2
@@ -92,11 +93,6 @@ class ArchConfig:
     frontend: str = "none"  # none | audio_stub | vision_stub
     frontend_dim: int = 0
     n_vision_tokens: int = 0
-    # probe/unroll controls (roofline cost correction — see launch/dryrun)
-    segment_override: Any = None  # Tuple[Tuple[str,int],...] replacing segments()
-    unroll_layers: bool = False  # python loop over layers instead of lax.scan
-    unroll_scans: bool = False  # unroll chunk scans (attention/mLSTM/SSM)
-    ssm_chunk: int = 2048
     # numerics / execution
     norm_eps: float = 1e-5
     tie_embeddings: bool = True
@@ -148,8 +144,6 @@ class ArchConfig:
 
     def segments(self) -> List[Tuple[str, int]]:
         """Homogeneous (block_kind, n_layers) runs, scanned independently."""
-        if self.segment_override is not None:
-            return [tuple(seg) for seg in self.segment_override]
         if self.family in ("dense", "audio", "vlm"):
             return [("dense", self.n_layers)]
         if self.family == "moe":
@@ -344,7 +338,6 @@ def _apply_attention(cfg: ArchConfig, p_attn: dict, x, positions, cache, update_
             norm_eps=cfg.norm_eps,
             backend=cfg.attn_backend,
             chunk=cfg.attn_chunk,
-            unroll=cfg.unroll_scans,
             cache=cache,
             update_cache=update_cache,
             yarn=cfg.yarn,
@@ -361,7 +354,6 @@ def _apply_attention(cfg: ArchConfig, p_attn: dict, x, positions, cache, update_
         norm_eps=cfg.norm_eps,
         backend=cfg.attn_backend,
         chunk=cfg.attn_chunk,
-        unroll=cfg.unroll_scans,
         cache=cache,
         update_cache=update_cache,
     )
@@ -385,7 +377,6 @@ def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, updat
                 n_state=cfg.ssm_state,
                 conv_w=cfg.ssm_conv,
                 chunk=cfg.ssm_chunk,
-                unroll=cfg.unroll_scans,
                 state=cache[1] if cache is not None else None,
                 update_state=update_cache,
             )
@@ -421,7 +412,6 @@ def _apply_block(cfg: ArchConfig, kind: str, p: dict, x, positions, cache, updat
             h,
             n_heads=cfg.n_heads,
             chunk=cfg.mlstm_chunk,
-            unroll=cfg.unroll_scans,
             state=state,
             update_state=update_cache,
             conv_state=conv_state,
@@ -453,9 +443,7 @@ def _apply_slstm_maybe_sharded(cfg: ArchConfig, p_slstm: dict, h, cache, update_
     axes = batch_axes()
 
     def run(pp, hh, st):
-        return xlstm_mod.apply_slstm(
-            pp, hh, n_heads=cfg.n_heads, state=st, update_state=update_cache, unroll=cfg.unroll_scans
-        )
+        return xlstm_mod.apply_slstm(pp, hh, n_heads=cfg.n_heads, state=st, update_state=update_cache)
 
     if mesh is None or not axes or h.shape[0] % int(np.prod([mesh.shape[a] for a in axes])) != 0:
         return run(p_slstm, h, cache)
@@ -550,18 +538,6 @@ def _scan_segment(cfg: ArchConfig, kind: str, p_seg: dict, x, positions, cache_s
 
     zero = jnp.zeros((), jnp.int32)
     carry = (x, jnp.zeros((), jnp.float32), (zero, zero), zero)
-    if cfg.unroll_layers:
-        n = jax.tree_util.tree_leaves(p_seg)[0].shape[0]
-        ys = []
-        for i in range(n):
-            xs_i = jax.tree_util.tree_map(lambda a: a[i], (p_seg, cache_seg))
-            carry, y = body(carry, xs_i)
-            ys.append(y)
-        new_caches = (
-            jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ys) if ys and ys[0] is not None else None
-        )
-        return (*carry[:3], new_caches)
-
     carry, new_caches = jax.lax.scan(body, carry, (p_seg, cache_seg))
     return (*carry[:3], new_caches)
 

@@ -3,7 +3,7 @@
 Training/prefill uses a parallel associative scan over the diagonal SSM
 recurrence (log-depth, TPU-friendly); decode is the O(1)-per-token recurrent
 step over carried (conv_state, ssm_state) — the sub-quadratic long-context
-path exercised by the ``long_500k`` shape.
+path.
 
 Recurrence (per channel c, state n):
     h_t = exp(Δ_t·A)·h_{t-1} + Δ_t·B_t·x_t
@@ -92,7 +92,7 @@ def _ssm_chunk(h_prev: Array, u, dt, a, b, c) -> Tuple[Array, Array]:
 
 
 def _ssm_scan_parallel(
-    u: Array, dt: Array, a: Array, b: Array, c: Array, chunk: int = 2048, unroll: bool = False
+    u: Array, dt: Array, a: Array, b: Array, c: Array, chunk: int = 2048
 ) -> Tuple[Array, Array]:
     """Chunked parallel scan: associative scan within chunks (log-depth,
     MXU-friendly), exact state carry across chunks — bounds the [B,L,D,N]
@@ -115,20 +115,13 @@ def _ssm_scan_parallel(
         return t.reshape(bsz, nc, chunk, *t.shape[2:]).transpose(1, 0, 2, *range(3, t.ndim + 1))
 
     xs = (split(u), split(dt), split(b), split(c))
-    if unroll:
-        h, ys = h0, []
-        for i in range(nc):
-            h, y_i = _ssm_chunk(h, xs[0][i], xs[1][i], a, xs[2][i], xs[3][i])
-            ys.append(y_i)
-        y = jnp.concatenate(ys, axis=1)
-    else:
 
-        def step(h, x):
-            h_new, y_i = _ssm_chunk(h, x[0], x[1], a, x[2], x[3])
-            return h_new, y_i
+    def step(h, x):
+        h_new, y_i = _ssm_chunk(h, x[0], x[1], a, x[2], x[3])
+        return h_new, y_i
 
-        h, ys = jax.lax.scan(step, h0, xs)
-        y = ys.transpose(1, 0, 2, 3).reshape(bsz, s + pad, d)
+    h, ys = jax.lax.scan(step, h0, xs)
+    y = ys.transpose(1, 0, 2, 3).reshape(bsz, s + pad, d)
     return y[:, :s], h
 
 
@@ -139,7 +132,6 @@ def apply_ssm(
     n_state: int,
     conv_w: int = 4,
     chunk: int = 2048,
-    unroll: bool = False,
     state: Optional[SSMState] = None,
     update_state: bool = False,
 ) -> Tuple[Array, Optional[SSMState]]:
@@ -194,7 +186,6 @@ def apply_ssm(
             b_mat.astype(jnp.float32),
             c_mat.astype(jnp.float32),
             chunk=chunk,
-            unroll=unroll,
         )
         y = y32.astype(x.dtype)
         if update_state and state is not None:

@@ -111,8 +111,7 @@ def _mlstm_chunk(carry, inp, scale):
 
 
 def mlstm_core(
-    q: Array, k: Array, v: Array, log_i: Array, log_f: Array, state: Optional[MLSTMState], chunk: int = 256,
-    unroll: bool = False,
+    q: Array, k: Array, v: Array, log_i: Array, log_f: Array, state: Optional[MLSTMState], chunk: int = 256
 ) -> Tuple[Array, MLSTMState]:
     """q,k,v [B,H,S,dh]; log gates [B,H,S]. Returns (h [B,H,S,dh], state)."""
     b, h, s, dh = q.shape
@@ -145,15 +144,8 @@ def mlstm_core(
             a.reshape(b, h, nc, chunk, *a.shape[3:]).transpose(2, 0, 1, 3, *range(4, a.ndim + 1))
             for a in (q, k, v)
         ) + tuple(a.reshape(b, h, nc, chunk).transpose(2, 0, 1, 3) for a in (log_i, log_f))
-        if unroll:
-            hs = []
-            for i in range(nc):
-                carry, h_i = _mlstm_chunk(carry, tuple(x[i] for x in xs), scale)
-                hs.append(h_i)
-            h_out = jnp.concatenate(hs, axis=2)[:, :, :s]
-        else:
-            carry, h_chunks = jax.lax.scan(step, carry, xs)
-            h_out = h_chunks.transpose(1, 2, 0, 3, 4).reshape(b, h, s + pad, dh)[:, :, :s]
+        carry, h_chunks = jax.lax.scan(step, carry, xs)
+        h_out = h_chunks.transpose(1, 2, 0, 3, 4).reshape(b, h, s + pad, dh)[:, :, :s]
     return h_out, MLSTMState(c=carry[0], n=carry[1], m=carry[2])
 
 
@@ -164,7 +156,6 @@ def apply_mlstm(
     n_heads: int,
     conv_w: int = 4,
     chunk: int = 256,
-    unroll: bool = False,
     state: Optional[MLSTMState] = None,
     update_state: bool = False,
     conv_state: Optional[Array] = None,
@@ -201,7 +192,7 @@ def apply_mlstm(
     log_i = gates[..., 0][:, None, :].repeat(n_heads, axis=1)  # [B,H,S]
     log_f = jax.nn.log_sigmoid(gates[..., 1])[:, None, :].repeat(n_heads, axis=1)
 
-    h_out, new_state = mlstm_core(q, k, v, log_i, log_f, state, chunk=chunk, unroll=unroll)
+    h_out, new_state = mlstm_core(q, k, v, log_i, log_f, state, chunk=chunk)
     h_out = h_out.transpose(0, 2, 1, 3).reshape(b, s, d_inner).astype(x.dtype)
     h_out = rms_norm(h_out, p["gn_scale"])  # per-channel GN stand-in
     h_out = h_out * jax.nn.silu(z)
@@ -283,7 +274,6 @@ def apply_slstm(
     n_heads: int,
     state: Optional[SLSTMState] = None,
     update_state: bool = False,
-    unroll: bool = False,
 ) -> Tuple[Array, Optional[SLSTMState]]:
     b, s, d = x.shape
     if state is None:
@@ -293,15 +283,6 @@ def apply_slstm(
     if s == 1:
         new_state, h = _slstm_step(p, n_heads, st32, x[:, 0])
         hs = h[:, None, :]
-    elif unroll and s <= 128:
-        # cost probes: unrolled time loop so every step's ops are counted
-        carry = st32
-        outs = []
-        for t in range(s):
-            carry, h = _slstm_step(p, n_heads, carry, x[:, t])
-            outs.append(h)
-        new_state = carry
-        hs = jnp.stack(outs, axis=1)
     else:
 
         def step(carry, x_t):

@@ -250,8 +250,17 @@ class TestRouterMergedView:
         assert rs.wait_hist == ts.wait_hist  # exact histogram merge
         router.close()
 
-    def test_merged_collect_equals_single_stage(self):
-        self._drive_and_compare([(i, 1024 * (i + 1), 10 + i) for i in range(5)])
+    @pytest.mark.parametrize(
+        "ops",
+        [
+            [(i, 1024 * (i + 1), 10 + i) for i in range(5)],
+            # one flow's requests differ in size: each must count its own bytes
+            [(1, 0, 1), (1, 1, 1), (3, 4096, 2), (3, 512, 3), (3, 4096, 1)],
+        ],
+        ids=["one_size_per_flow", "mixed_sizes_per_flow"],
+    )
+    def test_merged_collect_equals_single_stage(self, ops):
+        self._drive_and_compare(ops)
         # and the flows really spread over more than one shard
         router, _ = _mk_router()
         owners = {router.owner_of(_ctx(t)) for t in TENANTS}
